@@ -46,7 +46,7 @@ REDUCE = {
 }
 
 
-def _grid(n: int):
+def fig4_grid(n: int):
     """n-point fig4 campaign with per-row workload scale (distinct rows,
     one compiled program)."""
     base = scenarios.fig4_scenario(0, 0)
@@ -69,7 +69,7 @@ def _timed(fn):
 def run() -> dict:
     report: dict = {}
 
-    batched = _grid(N_STREAMING)
+    batched = fig4_grid(N_STREAMING)
     dt, out = _timed(
         lambda: run_campaign(batched, chunk_size=CHUNK, reduce=REDUCE)
     )
@@ -86,7 +86,7 @@ def run() -> dict:
 
     devs = jax.devices()
     mesh = Mesh(devs, ("data",))
-    batched_s = _grid(N_SHARDED)
+    batched_s = fig4_grid(N_SHARDED)
     dt, out = _timed(
         lambda: run_campaign(batched_s, chunk_size=CHUNK, mesh=mesh,
                              reduce=REDUCE)

@@ -99,15 +99,30 @@ def bench_single(n_rep: int = 3) -> dict:
     return rows
 
 
-def bench_batch(b: int = BATCH) -> dict:
-    scn_b = stack_scenarios(
-        [bench_scenario(1.0 + 0.002 * i) for i in range(b)]
+def batch_stack(b: int = BATCH) -> Scenario:
+    """``b`` rows of the benchmark scenario with staggered task lengths."""
+    return stack_scenarios([bench_scenario(1.0 + 0.002 * i) for i in range(b)])
+
+
+def vmap_simulate(scn_b: Scenario):
+    """The baseline the batch-major refactor replaces: the campaign axis in
+    an outer vmap, which turns every phase cond into a select."""
+    return jax.vmap(lambda s: simulate_instrumented(s)[0])(scn_b)
+
+
+def bitwise_equal(a, b) -> bool:
+    return all(
+        bool(jnp.array_equal(x, y))
+        for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b))
     )
+
+
+def bench_batch(b: int = BATCH) -> dict:
+    scn_b = batch_stack(b)
 
     # rank detection routes the stacked pytree through the batch-major loop
     run_batch = jax.jit(simulate)
-    # the baseline the refactor replaces: campaign axis in an outer vmap
-    run_vmap = jax.jit(jax.vmap(lambda s: simulate_instrumented(s)[0]))
+    run_vmap = jax.jit(vmap_simulate)
 
     res_b = run_batch(scn_b)
     n_events = int(np.asarray(res_b.n_events).sum())
@@ -115,10 +130,7 @@ def bench_batch(b: int = BATCH) -> dict:
     res_v = run_vmap(scn_b)
     wall_v = _time(run_vmap, scn_b, n_rep=1)
 
-    bitwise = all(
-        bool(jnp.array_equal(x, y)) for x, y in
-        zip(jax.tree.leaves(res_b), jax.tree.leaves(res_v))
-    )
+    bitwise = bitwise_equal(res_b, res_v)
     return {
         "batch_size": b,
         "n_events": n_events,

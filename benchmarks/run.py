@@ -28,6 +28,8 @@ import sys
 import time
 import traceback
 
+from repro.compile_cache import enable_compile_cache
+
 # (section title, module under benchmarks/, JSON artifact or None)
 SECTIONS = (
     ("Figure 7/8: instantiation time & memory (100 -> 100k hosts)",
@@ -93,6 +95,7 @@ def main(argv=None) -> int:
                     help="after a fully-green run, rewrite "
                          f"{BASELINE_PATH} from the fresh artifacts")
     args = ap.parse_args(argv)
+    print(f"# compile cache: {enable_compile_cache()}")
     t_all = time.time()
     report: dict = {"sections": {}, "ok": True}
 

@@ -40,8 +40,9 @@ R5     recompile-hazard    tracing the same entry across two scenario
                            one compiled streaming-fold program (the
                            one-compiled-program property, DESIGN.md §5/§12)
 R6     kernel-budget       the fused advance kernel's launch plan respects the
-                           ``ops.advance_block`` heuristic bounds and declares
-                           its ``[B]`` SMEM operands scalar-per-row
+                           ``ops.advance_block`` heuristic bounds, tiles eight
+                           sublane rows per grid step and declares its ``[B]``
+                           SMEM operands scalar-per-row
 =====  ==================  =====================================================
 
 The rule bodies are thin wrappers over pure ``check_*`` functions operating
@@ -550,7 +551,10 @@ def check_rung_reuse(n_new_first: int, n_new_repeat: int, entry: str,
 def check_kernel_plan(plan: dict, n_cloudlets: int, max_block: int,
                       entry: str, rule_id: str = "R6") -> list[Finding]:
     """Audit one advance-kernel launch plan against the ``advance_block``
-    heuristic bounds and the SMEM scalar-per-row contract."""
+    heuristic bounds, the sublane-aligned tile and the SMEM scalar-per-row
+    contract."""
+    from repro.kernels.vm_update import ROWS as rows
+
     findings = []
     block, b = plan["block"], plan["b"]
 
@@ -574,19 +578,24 @@ def check_kernel_plan(plan: dict, n_cloudlets: int, max_block: int,
     if plan["variant"] != want_variant:
         err(f"variant {plan['variant']!r} but nb={nb} implies "
             f"{want_variant!r}")
-    want_grid = (b,) if nb == 1 else (b, 2, nb)
+    padded_b = plan["padded_b"]
+    if padded_b % rows or not b <= padded_b < b + rows:
+        err(f"padded batch {padded_b} is not B={b} rounded up to a "
+            f"multiple of {rows} sublanes")
+    groups = padded_b // rows
+    want_grid = (groups,) if nb == 1 else (groups, 2, nb)
     if tuple(plan["grid"]) != want_grid:
         err(f"grid {tuple(plan['grid'])} != expected {want_grid}")
-    if tuple(plan["tile"]) != (1, block):
-        err(f"tile {tuple(plan['tile'])} != (1, {block}) — more than one "
-            "scenario row resident per grid step")
+    if tuple(plan["tile"]) != (rows, block):
+        err(f"tile {tuple(plan['tile'])} != ({rows}, {block}) — Mosaic "
+            f"needs {rows} f32 sublanes per tile")
     for kind in ("smem_in", "smem_out"):
         for name, shape in plan[kind]:
-            if tuple(shape) != (b,):
+            if tuple(shape) != (padded_b,):
                 err(f"SMEM operand {name!r} has shape {tuple(shape)}; "
-                    f"[B]=({b},) scalars-per-row required")
-    if plan["variant"] == "fused" and plan["smem_scratch"]:
-        err("fused variant declares SMEM scratch it never reads")
+                    f"[B]=({padded_b},) scalars-per-row required")
+    if plan["variant"] == "fused" and plan["vmem_scratch"]:
+        err("fused variant declares VMEM scratch it never reads")
     return findings
 
 
@@ -756,7 +765,8 @@ def _rule_recompile_hazard(ctx: LintContext) -> list[Finding]:
 
 # n_cloudlets probes for R6: around the floor, a mid-size, both sides of the
 # pow-2 boundary, and both sides of the VMEM cap (the fallback frontier).
-_R6_SIZES = (1, 7, 96, 128, 129, 1000, 4096, 1 << 17, (1 << 17) + 1, 3 << 17)
+def _r6_sizes(cap: int) -> tuple[int, ...]:
+    return (1, 7, 96, 128, 129, 1000, 4096, cap, cap + 1, 3 * cap)
 
 
 @rule("R6", "kernel-budget", entries=("advance_pallas",))
@@ -766,7 +776,7 @@ def _rule_kernel_budget(ctx: LintContext) -> list[Finding]:
     if not ctx.wants("advance_pallas"):
         return []
     findings = []
-    for n in _R6_SIZES:
+    for n in _r6_sizes(ops._MAX_BLOCK):
         block = ops.advance_block(n)
         plan = vm_update.kernel_plan(_BATCH, n, block)
         findings += check_kernel_plan(

@@ -35,7 +35,6 @@ import jax.numpy as jnp
 from repro.core.engine import simulate
 from repro.core.entities import Scenario, SimResult
 from repro.core.reducers import CampaignReducer
-from repro.dist.compat import shard_map as _shard_map
 
 
 def stack_scenarios(scenarios: list[Scenario]) -> Scenario:
@@ -123,7 +122,7 @@ def _sharded_simulate(chunk: Scenario, mesh, axis: str) -> SimResult:
     else replicated.  Each shard's sub-campaign keeps its leading rank, so
     ``engine.is_batched`` still routes it through the batch-major step —
     per-shard results are bitwise those of the unsharded run.  Replication
-    checking is off (the compat shim): the while-loop carry mixes varying
+    checking is off (``check_vma=False``): the while-loop carry mixes varying
     per-row state with scalars the static checker cannot prove replicated.
     """
     from repro.dist.sharding import campaign_pspec_tree
@@ -138,8 +137,9 @@ def _sharded_simulate(chunk: Scenario, mesh, axis: str) -> SimResult:
             f"{axis!r} (size {dict(mesh.shape)[axis]}); pick a chunk_size "
             "that divides"
         )
-    run = _shard_map(
-        simulate, mesh=mesh, in_specs=(in_tree,), out_specs=pspec(axis)
+    run = jax.shard_map(
+        simulate, mesh=mesh, in_specs=(in_tree,), out_specs=pspec(axis),
+        check_vma=False,
     )
     return run(chunk)
 

@@ -24,6 +24,7 @@ engine serves an entire campaign (policy x seed x workload sweep) via vmap.
 from __future__ import annotations
 
 import jax.numpy as jnp
+import numpy as np
 from jax import Array
 
 from repro.core.pytree import pytree_dataclass
@@ -32,8 +33,10 @@ from repro.core.pytree import pytree_dataclass
 SPACE_SHARED = 0
 TIME_SHARED = 1
 
-# A time/MI that behaves as "never/unreachable".
-INF = jnp.float32(3.0e38)
+# A time/MI that behaves as "never/unreachable".  A numpy scalar, not a
+# device array: a module constant must not be a buffer that a donating call
+# (the campaign fold donates its reducer carries) can delete.
+INF = np.float32(3.0e38)
 
 
 @pytree_dataclass

@@ -10,10 +10,10 @@ Two halves:
   ``activation_shardings`` context models consult while tracing
   (``shard_act`` constraints, ``current_state`` for schedule selection).
 
-``repro.dist.compat`` carries the jax-version shard_map shim used by every
-shard_map call site in the tree.
+Every shard_map call site in the tree calls ``jax.shard_map`` with
+``check_vma=False`` directly.
 """
-from repro.dist import act_sharding, compat, sharding
+from repro.dist import act_sharding, sharding
 from repro.dist.act_sharding import activation_shardings, current_state, shard_act
 from repro.dist.sharding import (
     Rules,
@@ -27,7 +27,6 @@ __all__ = [
     "Rules",
     "act_sharding",
     "activation_shardings",
-    "compat",
     "current_state",
     "input_pspec_tree",
     "named",

@@ -1,9 +1,11 @@
 """Jitted public wrappers for the kernel layer.
 
 Routing policy:
-  * On CPU (this container) the Pallas kernels run in ``interpret=True`` —
-    bit-faithful to the kernel body, executed in Python, used by tests.
   * On TPU (the target) ``interpret=False`` compiles to Mosaic.
+  * On CPU the Pallas kernels run in ``interpret=True`` — bit-faithful to
+    the kernel body, executed in Python, used by tests.
+  * Any other backend has neither Mosaic nor a place in the tests: the
+    Pallas wrappers raise there rather than interpret in silence.
   * The models/engine default to the pure-jnp reference implementations
     (ref.py), which XLA fuses well and which lower on any backend; the
     Pallas path is selected via config (``attn_impl="pallas"`` etc.).
@@ -19,14 +21,25 @@ from repro.kernels.ssd_scan import ssd_scan_pallas
 from repro.kernels.vm_update import advance_sweep_pallas
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _interpret() -> bool:
+    """Compile to Mosaic on a TPU, interpret on the CPU, refuse elsewhere."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        "Pallas kernels compile only for a TPU (Mosaic) and interpret only "
+        f"on the CPU; the default backend is {backend!r}"
+    )
 
 
-# Largest single tile the fused advance kernel keeps resident per scenario
-# row: 2**17 f32 elements x 4 streams = 2 MB, comfortably inside VMEM.  Rows
-# longer than this fall back to the per-row two-phase sub-grid.
-_MAX_BLOCK = 1 << 17
+# Longest row tile the fused advance kernel keeps resident: one (8, 2**15)
+# f32 tile is 1 MiB, so the four streamed operands, double-buffered, take
+# 8 MiB — inside v5e's 16 MiB default scoped VMEM (2**16 overflows it in the
+# two-phase variant).  Rows longer than this take the per-row two-phase
+# sub-grid.
+_MAX_BLOCK = 1 << 15
 
 
 def advance_block(n_cloudlets: int) -> int:
@@ -51,14 +64,14 @@ def advance_sweep(rem: Array, rate: Array, active: Array, bound_dt: Array):
     return advance_sweep_pallas(
         rem, rate, active, bound_dt,
         block=advance_block(rem.shape[-1]),
-        interpret=not _on_tpu(),
+        interpret=_interpret(),
     )
 
 
 def resolve_advance(impl: str):
     """The single advance-sweep routing point (core.step.resolve_advance
     defers here): ``"jnp"`` -> the fusable reference, ``"pallas"`` -> the
-    fused batch-grid Mosaic kernel (interpret mode off-TPU).  Both
+    fused batch-grid Mosaic kernel (interpret mode on the CPU).  Both
     implementations pick batch-major vs per-scenario by input rank."""
     if impl == "pallas":
         return advance_sweep
@@ -76,13 +89,13 @@ def flash_attention(
 ) -> Array:
     return flash_attention_pallas(
         q, k, v, causal=causal, window=window, softcap=softcap, scale=scale,
-        interpret=not _on_tpu(),
+        interpret=_interpret(),
     )
 
 
 def ssd_scan(x, dt, A, Bm, Cm, D, *, chunk: int = 128) -> Array:
     return ssd_scan_pallas(
-        x, dt, A, Bm, Cm, D, chunk=chunk, interpret=not _on_tpu()
+        x, dt, A, Bm, Cm, D, chunk=chunk, interpret=_interpret()
     )
 
 

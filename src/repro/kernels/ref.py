@@ -9,9 +9,11 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import Array
 
-INF = jnp.float32(3.0e38)
+# numpy scalar, not a device array (see core/entities.py INF)
+INF = np.float32(3.0e38)
 
 
 # ---------------------------------------------------------------------------

@@ -279,8 +279,6 @@ def _decode_flash_lsharded(cfg, mesh, rules, q, kT, vT, k_cache, v_cache,
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.dist.compat import shard_map
-
     tp = rules.tp
     B = q.shape[0]
     Hk, L = k_cache.shape[1], k_cache.shape[2]
@@ -340,9 +338,9 @@ def _decode_flash_lsharded(cfg, mesh, rules, q, kT, vT, k_cache, v_cache,
         return out.astype(kT.dtype), kc, vc
 
     # `out` IS replicated over tp (every shard computes the same merge from
-    # the gathered stats) — the compat shim disables the static replication
+    # the gathered stats) — check_vma=False disables the static replication
     # checker, which can't see that
-    out, kc, vc = shard_map(
+    out, kc, vc = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(
@@ -358,6 +356,7 @@ def _decode_flash_lsharded(cfg, mesh, rules, q, kT, vT, k_cache, v_cache,
             P(bspec, None, tp, None),
             P(bspec, None, tp, None),
         ),
+        check_vma=False,
     )(q, kT, vT, k_cache, v_cache, pos)
     out = jnp.swapaxes(out, 1, 2).reshape(B, 1, cfg.n_heads * cfg.d_head)
     return out, (kc, vc)

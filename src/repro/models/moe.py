@@ -33,7 +33,6 @@ from jax import Array
 from jax.sharding import PartitionSpec as P
 
 from repro.dist import act_sharding
-from repro.dist.compat import shard_map
 from repro.models import layers
 
 
@@ -214,7 +213,7 @@ def _moe_shard_map(params: dict, cfg, x: Array, state) -> tuple[Array, Array]:
         out = checkpoint_name(out, "remat_ckpt")
         return out, aux
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(
@@ -225,6 +224,7 @@ def _moe_shard_map(params: dict, cfg, x: Array, state) -> tuple[Array, Array]:
             P(tp, "data", None),
         ),
         out_specs=(P(bspec, tp if sp_out else None, None), P()),
+        check_vma=False,
     )(x, params["router"], params["w_gate"], params["w_up"], params["w_down"])
     return out, aux
 

@@ -231,5 +231,6 @@ def test_advance_block_heuristic():
     assert ops.advance_block(1) == 128          # floor: one lane-width tile
     assert ops.advance_block(128) == 128
     assert ops.advance_block(129) == 256        # next pow2 covering the row
-    assert ops.advance_block(100_000) == 1 << 17
+    assert ops.advance_block(20_000) == 1 << 15
+    assert ops.advance_block(100_000) == ops._MAX_BLOCK
     assert ops.advance_block(1 << 20) == ops._MAX_BLOCK  # cap

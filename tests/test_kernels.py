@@ -92,7 +92,7 @@ def test_advance_ragged_tiles_parity(c, block, nb):
 @pytest.mark.tier1
 def test_advance_resolver_fallback_frontier():
     """Through ``ops.resolve_advance`` the two-phase path only engages past
-    the 2**17 tile cap: C = 3 * 2**17 is the smallest non-pow-2-nb row the
+    the tile cap: C = 3 * ``_MAX_BLOCK`` is the smallest non-pow-2-nb row the
     resolver can actually produce (nb = 3)."""
     from repro.kernels import ops
     from repro.kernels.vm_update import kernel_plan

@@ -7,10 +7,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import run_campaign, scenarios, stack_scenarios
+from repro.core import run_campaign, scenarios, simulate, stack_scenarios
 from repro.core.reducers import (
     ArgBestReducer,
     HistogramReducer,
+    LatencyHistogramReducer,
     MeanReducer,
     SumReducer,
     ValuesReducer,
@@ -151,3 +152,61 @@ def test_reducer_validation():
         ArgBestReducer("makespan", mode="best")
     with pytest.raises(TypeError, match="CampaignReducer"):
         run_campaign(batched, reduce={"x": jnp.sum})
+
+
+def test_argbest_fold_leaves_simulate_working():
+    """The fold donates its reducer carries.  ArgBest's initial best used to
+    be the module-level ``entities.INF`` device array itself, so the first
+    fold deleted that constant and every later ``simulate`` in the process
+    failed with "Array has been deleted"."""
+    batched = stack_scenarios([scenarios.fig4_scenario(0, 0)] * 4)
+    reducer = ArgBestReducer("mean_turnaround")
+    for _ in range(2):
+        out = run_campaign(batched, chunk_size=2, reduce=reducer)
+        assert int(out["index"]) == 0
+    res = jax.jit(simulate)(scenarios.fig4_scenario(0, 0))
+    assert int(res.n_finished) == 8
+
+
+def test_module_constants_are_not_device_arrays():
+    """No module of the package holds a device array: importing it touches
+    no backend, and no donating call can delete a shared constant."""
+    import sys
+
+    from repro.core import entities
+    from repro.kernels import ref
+
+    assert not isinstance(entities.INF, jax.Array)
+    assert not isinstance(ref.INF, jax.Array)
+    held = [
+        f"{name}.{attr}"
+        for name, mod in list(sys.modules.items()) if name.startswith("repro")
+        for attr, value in vars(mod).items() if isinstance(value, jax.Array)
+    ]
+    assert held == []
+
+
+def test_importing_the_engine_touches_no_backend():
+    """A fresh process imports ``repro.core`` and the kernel router without
+    bringing up any JAX backend: a module-level device array would."""
+    import os
+    import subprocess
+    import sys
+
+    code = ("import repro.core, repro.core.reducers, repro.kernels.ops\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge.backends_are_initialized()\n")
+    proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                          text=True, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+                          timeout=120)
+    assert proc.returncode == 0, proc.stderr
+
+
+def test_reducer_carries_are_fresh_buffers():
+    """Every ``init`` builds new buffers: the fold donates them, so a carry
+    leaf that something else still holds would be deleted under it."""
+    batched = stack_scenarios([scenarios.fig4_scenario(0, 0)] * 2)
+    res_avals = jax.eval_shape(simulate, batched)
+    for r in (*REDUCE.values(), LatencyHistogramReducer("ttft", 0.0, 10.0)):
+        a, b = (jax.tree.leaves(r.init(batched, res_avals)) for _ in range(2))
+        assert all(x is not y for x, y in zip(a, b)), r

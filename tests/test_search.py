@@ -104,9 +104,26 @@ def test_successive_halving_finds_optimum_and_reuses_program():
     out2 = successive_halving(tmpl, space, key=jax.random.PRNGKey(9), **kw)
     assert size() - before == first, "knob values leaked into the jit cache"
 
+    # The winner is the best combo the search SAMPLED.  Eight uniform draws
+    # from four combos need not include space/space (0, 0): under jax's
+    # threefry-partitionable random bits, key 9 draws vm_policy == 1 eight
+    # times.  Score every combo exhaustively and compare against the sample.
+    combos = grid_params(space)
+    full = np.array(run_campaign(build_campaign(tmpl, combos)).mean_turnaround)
+    score = {(int(h), int(v)): t for h, v, t in zip(
+        np.array(combos["host_policy"]), np.array(combos["vm_policy"]), full)}
     for res in (out, out2):
-        assert int(res["best_params"]["host_policy"]) == 0
-        assert int(res["best_params"]["vm_policy"]) == 0
+        sampled = set(zip(np.array(res["params"]["host_policy"]).tolist(),
+                          np.array(res["params"]["vm_policy"]).tolist()))
+        best = (int(res["best_params"]["host_policy"]),
+                int(res["best_params"]["vm_policy"]))
+        assert best in sampled
+        assert score[best] == min(score[c] for c in sampled)
+    # key 1 samples space/space, fig4's optimum, and the search finds it
+    assert (0, 0) in set(zip(np.array(out["params"]["host_policy"]).tolist(),
+                             np.array(out["params"]["vm_policy"]).tolist()))
+    assert (int(out["best_params"]["host_policy"]),
+            int(out["best_params"]["vm_policy"])) == (0, 0)
     ns = [r["candidates"].shape[0] for r in out["rungs"]]
     assert ns == [8, 4]
     assert [r["fidelity"] for r in out["rungs"]] == [4000.0, 8000.0]

@@ -11,10 +11,7 @@ from repro.dist import param_pspec_tree, input_pspec_tree
 def _fake_mesh(shape, axes):
     """Abstract mesh for spec derivation only (no real devices needed)."""
     from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(shape, axes)          # jax >= 0.5
-    except TypeError:
-        return AbstractMesh(tuple(zip(axes, shape)))  # jax 0.4.x: (name, size) pairs
+    return AbstractMesh(shape, axes)
 
 
 MESH = _fake_mesh((16, 16), ("data", "model"))

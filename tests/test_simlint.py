@@ -248,6 +248,19 @@ class TestR6KernelBudget:
         errs = _errors(simlint.check_kernel_plan(plan, 128, 1 << 17, "t"))
         assert any("scalars-per-row" in e.message for e in errs)
 
+    def test_single_row_tile_trips(self):
+        # the (1, block) tile Mosaic refuses for B > 1
+        plan = vm_update.kernel_plan(4, 128, 128)
+        plan["tile"] = (1, 128)
+        errs = _errors(simlint.check_kernel_plan(plan, 128, 1 << 17, "t"))
+        assert any("sublanes per tile" in e.message for e in errs)
+
+    def test_unpadded_batch_trips(self):
+        plan = vm_update.kernel_plan(4, 128, 128)
+        plan["padded_b"], plan["grid"] = 4, (4,)
+        errs = _errors(simlint.check_kernel_plan(plan, 128, 1 << 17, "t"))
+        assert any("multiple of 8 sublanes" in e.message for e in errs)
+
     def test_doctored_variant_trips(self):
         plan = vm_update.kernel_plan(4, 128, 128)
         plan["variant"], plan["grid"] = "two_phase", (4, 2, 1)
@@ -256,7 +269,7 @@ class TestR6KernelBudget:
 
     def test_fused_scratch_trips(self):
         plan = vm_update.kernel_plan(4, 128, 128)
-        plan["smem_scratch"] = (("min_sc", (1,)),)
+        plan["vmem_scratch"] = (("min_sc", (8, 1)),)
         errs = _errors(simlint.check_kernel_plan(plan, 128, 1 << 17, "t"))
         assert any("scratch" in e.message for e in errs)
 
