@@ -35,6 +35,17 @@ import jax.numpy as jnp
 from repro.core.engine import simulate
 from repro.core.entities import Scenario, SimResult
 from repro.core.reducers import CampaignReducer
+from repro.core.step import SCOPE_FOLD
+
+# Host spans (``jax.profiler.TraceAnnotation``) of a streaming sweep, on the
+# device trace's clock: they say what the host was doing while the chip
+# idled between chunk programs.  They record only while the profiler is on.
+SPAN_PREPARE = "campaign_prepare"    # once: avals, eval_shape, carries, shardings
+SPAN_SLICE = "campaign_slice"        # per chunk: slice and pad every leaf
+SPAN_PUT = "campaign_put"            # per chunk, with a mesh: device_put
+SPAN_LAUNCH = "campaign_launch"      # per chunk: dispatch of the fold program
+SPAN_FINALIZE = "campaign_finalize"  # once: the reducers' finalize
+HOST_SPANS = (SPAN_PREPARE, SPAN_SLICE, SPAN_PUT, SPAN_LAUNCH, SPAN_FINALIZE)
 
 
 def stack_scenarios(scenarios: list[Scenario]) -> Scenario:
@@ -239,13 +250,14 @@ def lower_chunk(chunk: Scenario, mesh=None, axis: str = "data") -> tuple[str, in
 def _run_chunk_fold(leaves, bounds, carries, treedef, reducers, mesh, axis):
     scn = jax.tree.unflatten(treedef, leaves)
     res = _sim_fn(mesh, axis)(scn)
-    size = jax.tree.leaves(scn)[0].shape[0]
-    index = bounds[0] + jnp.arange(size, dtype=jnp.int32)
-    valid = index < bounds[1]
-    return tuple(
-        r.fold(c, scn, res, index, valid)
-        for r, c in zip(reducers, carries)
-    )
+    with jax.named_scope(SCOPE_FOLD):
+        size = jax.tree.leaves(scn)[0].shape[0]
+        index = bounds[0] + jnp.arange(size, dtype=jnp.int32)
+        valid = index < bounds[1]
+        return tuple(
+            r.fold(c, scn, res, index, valid)
+            for r, c in zip(reducers, carries)
+        )
 
 
 def _normalize_reduce(reduce):
@@ -264,32 +276,36 @@ def _normalize_reduce(reduce):
 
 def _run_reduced(batched: Scenario, chunk_size: int | None, reduce,
                  mesh, axis: str):
+    span = jax.profiler.TraceAnnotation
     keys, reducers, single = _normalize_reduce(reduce)
     n = _campaign_len(batched)
     chunk = chunk_size or n
 
-    leaves0, treedef = jax.tree.flatten(batched)
-    chunk_avals = jax.tree.unflatten(treedef, [
-        jax.ShapeDtypeStruct((chunk,) + l.shape[1:], l.dtype)
-        for l in leaves0
-    ])
-    res_avals = jax.eval_shape(simulate, chunk_avals)
-    carries = tuple(r.init(chunk_avals, res_avals) for r in reducers)
+    with span(SPAN_PREPARE):
+        leaves0, treedef = jax.tree.flatten(batched)
+        chunk_avals = jax.tree.unflatten(treedef, [
+            jax.ShapeDtypeStruct((chunk,) + l.shape[1:], l.dtype)
+            for l in leaves0
+        ])
+        res_avals = jax.eval_shape(simulate, chunk_avals)
+        carries = tuple(r.init(chunk_avals, res_avals) for r in reducers)
 
-    # With a mesh, pin every input's sharding before each fold call:
-    # otherwise arrays that flow back from a previous fold (search-driver
-    # survivors, the carries themselves) arrive committed to mesh shardings
-    # while fresh chunks arrive uncommitted, and the differing shardings
-    # fork the jit cache per call — the exact hazard simlint R5 probes.
-    leaf_shardings = rep = None
-    if mesh is not None:
-        from repro.dist.sharding import campaign_pspec_tree, named
+        # With a mesh, pin every input's sharding before each fold call:
+        # otherwise arrays that flow back from a previous fold (search-driver
+        # survivors, the carries themselves) arrive committed to mesh
+        # shardings while fresh chunks arrive uncommitted, and the differing
+        # shardings fork the jit cache per call — the exact hazard simlint
+        # R5 probes.
+        leaf_shardings = rep = None
+        if mesh is not None:
+            from repro.dist.sharding import campaign_pspec_tree, named
 
-        leaf_shardings = jax.tree.leaves(
-            named(mesh, campaign_pspec_tree(chunk_avals, mesh, axis)),
-            is_leaf=lambda x: isinstance(x, jax.sharding.NamedSharding),
-        )
-        rep = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
+            leaf_shardings = jax.tree.leaves(
+                named(mesh, campaign_pspec_tree(chunk_avals, mesh, axis)),
+                is_leaf=lambda x: isinstance(x, jax.sharding.NamedSharding),
+            )
+            rep = jax.sharding.NamedSharding(
+                mesh, jax.sharding.PartitionSpec())
 
     for lo in range(0, n, chunk):
         def _slice(x):
@@ -300,19 +316,24 @@ def _run_reduced(batched: Scenario, chunk_size: int | None, reduce,
                 c = jnp.concatenate([c, pad])
             return c
 
-        leaves = tuple(jax.tree.leaves(jax.tree.map(_slice, batched)))
+        with span(SPAN_SLICE):
+            leaves = tuple(jax.tree.leaves(jax.tree.map(_slice, batched)))
         if mesh is not None:
-            leaves = tuple(
-                jax.device_put(l, s) for l, s in zip(leaves, leaf_shardings)
-            )
-            carries = jax.device_put(carries, rep)
+            with span(SPAN_PUT):
+                leaves = tuple(
+                    jax.device_put(l, s)
+                    for l, s in zip(leaves, leaf_shardings)
+                )
+                carries = jax.device_put(carries, rep)
         # (lo, n) ride as one traced i32[2] so every chunk — first, middle,
         # padded tail — reuses the same compiled fold program
-        bounds = jnp.asarray([lo, n], jnp.int32)
-        carries = _run_chunk_fold(
-            leaves, bounds, carries, treedef, reducers, mesh, axis
-        )
-    outs = tuple(r.finalize(c) for r, c in zip(reducers, carries))
+        with span(SPAN_LAUNCH):
+            bounds = jnp.asarray([lo, n], jnp.int32)
+            carries = _run_chunk_fold(
+                leaves, bounds, carries, treedef, reducers, mesh, axis
+            )
+    with span(SPAN_FINALIZE):
+        outs = tuple(r.finalize(c) for r, c in zip(reducers, carries))
     if keys is not None:
         return dict(zip(keys, outs))
     return outs[0] if single else outs

@@ -148,18 +148,23 @@ def simulate_instrumented(
     """
     if is_batched(scn):
         return _simulate_instrumented_batch(scn, tuple(extra_instruments))
-    ctx, aux0 = make_context(scn, tuple(extra_instruments))
+    with jax.named_scope(step_mod.SCOPE_INIT):
+        ctx, aux0 = make_context(scn, tuple(extra_instruments))
+        st0 = init_state(scn)
     max_steps = step_mod.resolve_max_steps(scn, ctx.instruments)
 
     def cond(carry) -> Array:
-        return step_mod.step_cond(scn, carry[0], max_steps)
+        with jax.named_scope(step_mod.SCOPE_LOOP_COND):
+            return step_mod.step_cond(scn, carry[0], max_steps)
 
     def body(carry):
         carry, _ = event_step(scn, carry, ctx)
         return carry
 
-    st, aux = jax.lax.while_loop(cond, body, (init_state(scn), aux0))
-    return finalize_result(scn, st), step_mod.finalize_outputs(scn, st, ctx, aux)
+    st, aux = jax.lax.while_loop(cond, body, (st0, aux0))
+    with jax.named_scope(step_mod.SCOPE_FINALIZE):
+        return (finalize_result(scn, st),
+                step_mod.finalize_outputs(scn, st, ctx, aux))
 
 
 def _simulate_instrumented_batch(
@@ -171,14 +176,16 @@ def _simulate_instrumented_batch(
     instrument-structure information, so the row-0 view stands in for every
     row (``stack_scenarios`` enforces static-field agreement).
     """
-    scn0 = scenario_row(scn_b)
-    ctx, _ = make_context(scn0, extras)
+    with jax.named_scope(step_mod.SCOPE_INIT):
+        scn0 = scenario_row(scn_b)
+        ctx, _ = make_context(scn0, extras)
+        st0 = jax.vmap(init_state)(scn_b)
+        aux0 = jax.vmap(lambda s: step_mod.init_aux(s, extras))(scn_b)
     max_steps = step_mod.resolve_max_steps(scn0, ctx.instruments)
-    st0 = jax.vmap(init_state)(scn_b)
-    aux0 = jax.vmap(lambda s: step_mod.init_aux(s, extras))(scn_b)
 
     def cond(carry) -> Array:
-        return jnp.any(step_mod.batch_live(scn_b, carry[0], max_steps))
+        with jax.named_scope(step_mod.SCOPE_LOOP_COND):
+            return jnp.any(step_mod.batch_live(scn_b, carry[0], max_steps))
 
     def body(carry):
         carry, _, _ = step_mod.batch_event_step(
@@ -187,10 +194,11 @@ def _simulate_instrumented_batch(
         return carry
 
     st, aux = jax.lax.while_loop(cond, body, (st0, aux0))
-    res = jax.vmap(finalize_result)(scn_b, st)
-    out = jax.vmap(
-        lambda s, f, a: step_mod.finalize_outputs_for(s, f, a, extras)
-    )(scn_b, st, aux)
+    with jax.named_scope(step_mod.SCOPE_FINALIZE):
+        res = jax.vmap(finalize_result)(scn_b, st)
+        out = jax.vmap(
+            lambda s, f, a: step_mod.finalize_outputs_for(s, f, a, extras)
+        )(scn_b, st, aux)
     return res, out
 
 

@@ -68,6 +68,26 @@ SCOPE_SERVING = "phase_serving"
 # simlint's lint scenarios carry one so R1 covers all four phases.
 PHASE_SCOPES = (SCOPE_PROVISION, SCOPE_DISPATCH, SCOPE_TRANSFER, SCOPE_SERVING)
 
+# Named scopes over the rest of the program, so a profiler trace assigns
+# nearly every device op to a phase (bench/metrics reads them by name).  They
+# sit on no ``conditional``, so they stay out of PHASE_SCOPES (simlint R1).
+# Each is opened at the call site, around a phase's ``jax.vmap`` on the batch
+# path: a scope opened inside a vmapped function renders as
+# ``vmap(phase_bound)`` and no longer matches as a whole path component.
+SCOPE_PROLOGUE = "phase_prologue"   # outage edges, transfers, pre hooks, release
+SCOPE_BOUND = "phase_bound"         # policy sweep, candidate times, bound hooks
+SCOPE_ADVANCE = "phase_advance"     # ctx.advance, jnp or Pallas
+SCOPE_COMMIT = "phase_commit"       # state update, post hooks
+SCOPE_LOOP_COND = "loop_cond"       # the while drivers' condition, batch_live
+SCOPE_FREEZE = "phase_freeze"       # batch path: finished rows' row select
+SCOPE_INIT = "sim_init"             # init_state / init_aux in the drivers
+SCOPE_FINALIZE = "sim_finalize"     # finalize_result / finalize_outputs
+SCOPE_FOLD = "campaign_fold"        # the reducers' folds in a chunk program
+TRACE_SCOPES = (
+    SCOPE_PROLOGUE, SCOPE_BOUND, SCOPE_ADVANCE, SCOPE_COMMIT, SCOPE_LOOP_COND,
+    SCOPE_FREEZE, SCOPE_INIT, SCOPE_FINALIZE, SCOPE_FOLD,
+)
+
 
 def default_max_steps(scn: Scenario) -> int:
     """Safety bound on event batches: starts + finishes + VM lifecycle + slack.
@@ -977,7 +997,8 @@ def event_step(
     st, aux = carry
     instruments = ctx.instruments
 
-    st, aux = _phase_prologue(scn, st, aux, instruments)
+    with jax.named_scope(SCOPE_PROLOGUE):
+        st, aux = _phase_prologue(scn, st, aux, instruments)
 
     # --- VM placement + broker dispatch, skipped when nothing is due ---
     with jax.named_scope(SCOPE_PROVISION):
@@ -1016,16 +1037,20 @@ def event_step(
             st,
         )
 
-    rate, vm_mips, active, bound_dt, cand_ts = _phase_bound(
-        scn, st, aux, instruments
-    )
+    with jax.named_scope(SCOPE_BOUND):
+        rate, vm_mips, active, bound_dt, cand_ts = _phase_bound(
+            scn, st, aux, instruments
+        )
 
     # --- fused advance: completion min-reduce + work depletion ---
-    dt, new_rem = ctx.advance(st.rem_mi, rate, active, bound_dt)
+    with jax.named_scope(SCOPE_ADVANCE):
+        dt, new_rem = ctx.advance(st.rem_mi, rate, active, bound_dt)
 
-    return _phase_commit(
-        scn, st, aux, instruments, rate, vm_mips, active, cand_ts, dt, new_rem
-    )
+    with jax.named_scope(SCOPE_COMMIT):
+        return _phase_commit(
+            scn, st, aux, instruments, rate, vm_mips, active, cand_ts, dt,
+            new_rem,
+        )
 
 
 # ---------------------------------------------------------------------------
@@ -1086,16 +1111,18 @@ def batch_event_step(
     """
     st_b, aux_b = carry
     extras = tuple(extra_instruments)
-    live = batch_live(scn_b, st_b, max_steps)
+    with jax.named_scope(SCOPE_LOOP_COND):
+        live = batch_live(scn_b, st_b, max_steps)
 
     def prologue(scn, st, aux):
         return _phase_prologue(scn, st, aux, instruments_for(scn, extras))
 
-    st1, aux1 = jax.vmap(prologue)(scn_b, st_b, aux_b)
+    with jax.named_scope(SCOPE_PROLOGUE):
+        st1, aux1 = jax.vmap(prologue)(scn_b, st_b, aux_b)
 
     # --- VM placement + broker dispatch: batch-global skip predicates ---
-    need_prov = jnp.any(jax.vmap(_provision_needed)(scn_b, st1) & live)
     with jax.named_scope(SCOPE_PROVISION):
+        need_prov = jnp.any(jax.vmap(_provision_needed)(scn_b, st1) & live)
         st2 = jax.lax.cond(
             need_prov,
             lambda s: jax.vmap(
@@ -1104,8 +1131,8 @@ def batch_event_step(
             lambda s: s,
             st1,
         )
-    need_disp = jnp.any(jax.vmap(_dispatch_needed)(scn_b, st2) & live)
     with jax.named_scope(SCOPE_DISPATCH):
+        need_disp = jnp.any(jax.vmap(_dispatch_needed)(scn_b, st2) & live)
         st3 = jax.lax.cond(
             need_disp,
             lambda s: jax.vmap(provision.dispatch_cloudlets)(scn_b, s),
@@ -1114,10 +1141,10 @@ def batch_event_step(
         )
 
     if scn_b.topology is not None:
-        need_xfer = jnp.any(
-            jax.vmap(provision.transfer_needed)(scn_b, st3) & live
-        )
         with jax.named_scope(SCOPE_TRANSFER):
+            need_xfer = jnp.any(
+                jax.vmap(provision.transfer_needed)(scn_b, st3) & live
+            )
             st3 = jax.lax.cond(
                 need_xfer,
                 lambda s: jax.vmap(provision.transfer_phase)(scn_b, s),
@@ -1125,8 +1152,10 @@ def batch_event_step(
                 st3,
             )
 
-    need_srv = jnp.any(jax.vmap(kvserve.serving_needed)(scn_b, st3) & live)
     with jax.named_scope(SCOPE_SERVING):
+        need_srv = jnp.any(
+            jax.vmap(kvserve.serving_needed)(scn_b, st3) & live
+        )
         st3 = jax.lax.cond(
             need_srv,
             lambda s: jax.vmap(kvserve.serving_phase)(scn_b, s),
@@ -1137,12 +1166,14 @@ def batch_event_step(
     def bound(scn, st, aux):
         return _phase_bound(scn, st, aux, instruments_for(scn, extras))
 
-    rate, vm_mips, active, bound_dt, cand_ts = jax.vmap(bound)(
-        scn_b, st3, aux1
-    )
+    with jax.named_scope(SCOPE_BOUND):
+        rate, vm_mips, active, bound_dt, cand_ts = jax.vmap(bound)(
+            scn_b, st3, aux1
+        )
 
     # --- batch-grid advance on the whole [B, C] block (outside the vmap) ---
-    dt, new_rem = ctx.advance(st3.rem_mi, rate, active, bound_dt)
+    with jax.named_scope(SCOPE_ADVANCE):
+        dt, new_rem = ctx.advance(st3.rem_mi, rate, active, bound_dt)
 
     def commit(scn, st, aux, rate, vm_mips, active, cand_ts, dt, new_rem):
         return _phase_commit(
@@ -1150,11 +1181,13 @@ def batch_event_step(
             rate, vm_mips, active, cand_ts, dt, new_rem,
         )
 
-    (st4, aux2), ev = jax.vmap(commit)(
-        scn_b, st3, aux1, rate, vm_mips, active, cand_ts, dt, new_rem
-    )
+    with jax.named_scope(SCOPE_COMMIT):
+        (st4, aux2), ev = jax.vmap(commit)(
+            scn_b, st3, aux1, rate, vm_mips, active, cand_ts, dt, new_rem
+        )
 
-    carry2 = _freeze(live, (st4, aux2), (st_b, aux_b))
+    with jax.named_scope(SCOPE_FREEZE):
+        carry2 = _freeze(live, (st4, aux2), (st_b, aux_b))
     return carry2, ev, live
 
 
