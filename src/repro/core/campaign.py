@@ -11,7 +11,9 @@ million-scenario product (DESIGN.md §12):
 * ``run_campaign(batched, chunk_size=...)`` — slice the campaign axis into
   fixed-size chunks through ONE compiled program (trailing chunk padded by
   repeating the last row, then trimmed/masked), donating each chunk's
-  output-aliasable buffers so working memory is bounded by one chunk.
+  output-aliasable buffers so working memory is bounded by one chunk.  The
+  host work around it is compiled too: a warm sweep issues a fixed number
+  of dispatches however many leaves the scenario has.
 * ``run_campaign(..., mesh=...)`` — shard each chunk's campaign axis across
   ``mesh[axis]`` via ``shard_map`` (PartitionSpecs from
   ``dist.sharding.campaign_pspec_tree``): shards simulate their rows fully
@@ -27,10 +29,12 @@ policy grids where every rung re-enters the same compiled chunk program.
 """
 from __future__ import annotations
 
+import dataclasses
 from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from repro.core.engine import simulate
 from repro.core.entities import Scenario, SimResult
@@ -40,11 +44,11 @@ from repro.core.step import SCOPE_FOLD
 # Host spans (``jax.profiler.TraceAnnotation``) of a streaming sweep, on the
 # device trace's clock: they say what the host was doing while the chip
 # idled between chunk programs.  They record only while the profiler is on.
-SPAN_PREPARE = "campaign_prepare"    # once: avals, eval_shape, carries, shardings
-SPAN_SLICE = "campaign_slice"        # per chunk: slice and pad every leaf
+SPAN_PREPARE = "campaign_prepare"    # once: the shape plan (cached), carries
+SPAN_SLICE = "campaign_slice"        # per chunk: the slice program
 SPAN_PUT = "campaign_put"            # per chunk, with a mesh: device_put
 SPAN_LAUNCH = "campaign_launch"      # per chunk: dispatch of the fold program
-SPAN_FINALIZE = "campaign_finalize"  # once: the reducers' finalize
+SPAN_FINALIZE = "campaign_finalize"  # once: the finalize program
 HOST_SPANS = (SPAN_PREPARE, SPAN_SLICE, SPAN_PUT, SPAN_LAUNCH, SPAN_FINALIZE)
 
 
@@ -167,6 +171,41 @@ def _run_whole_sharded(batched: Scenario, mesh, axis: str) -> SimResult:
 
 
 # --------------------------------------------------------------------------
+# chunk slicing: one compiled program per chunk, not one eager op per leaf
+#
+# Eager indexing dispatches an op per leaf per chunk, and on the chip each
+# costs far more host time than the copy it asks for.  The slice program
+# takes every leaf and the chunk start as a traced i32, so every full chunk
+# of a campaign shares one executable; only the trailing short chunk pads,
+# and whether it does is decided on the host, so a (grid shape, chunk) pair
+# has at most two.  The grid is not donated: callers sweep it again.
+# --------------------------------------------------------------------------
+
+@partial(jax.jit, static_argnums=(2, 3))
+def _slice_program(leaves, lo, chunk: int, pad: bool):
+    n = leaves[0].shape[0]
+    if pad:
+        # clipped rows repeat the last row bit for bit
+        rows = lo + jnp.arange(chunk, dtype=jnp.int32)
+        part = tuple(jnp.take(x, rows, axis=0, mode="clip") for x in leaves)
+    else:
+        part = tuple(lax.dynamic_slice_in_dim(x, lo, chunk) for x in leaves)
+    return part, jnp.asarray([lo, n], jnp.int32)
+
+
+def _slice_chunk(leaves, lo: int, chunk: int):
+    """Rows ``[lo, lo + chunk)`` of every campaign leaf, the trailing short
+    chunk padded by repeating the last row, and the fold's ``(lo, n)``
+    bounds as an i32[2] on the device."""
+    n = leaves[0].shape[0]
+    return _slice_program(tuple(leaves), lo, chunk, lo + chunk > n)
+
+
+def _avals(leaves) -> tuple:
+    return tuple((l.shape, l.dtype) for l in leaves)
+
+
+# --------------------------------------------------------------------------
 # chunked execution with *effective* buffer donation
 #
 # Donating the whole Scenario pytree is a no-op that warns on every chunk
@@ -213,8 +252,7 @@ def _run_chunk_split(donated, kept, mask, treedef, mesh=None, axis="data"):
 def _split_chunk(chunk: Scenario):
     """(donated leaves, kept leaves, mask, treedef) for the chunk runner."""
     leaves, treedef = jax.tree.flatten(chunk)
-    avals = tuple((l.shape, l.dtype) for l in leaves)
-    mask = _donate_mask(treedef, avals)
+    mask = _donate_mask(treedef, _avals(leaves))
     donated = tuple(l for l, m in zip(leaves, mask) if m)
     kept = tuple(l for l, m in zip(leaves, mask) if not m)
     return donated, kept, mask, treedef
@@ -274,6 +312,60 @@ def _normalize_reduce(reduce):
     )
 
 
+@dataclasses.dataclass(frozen=True, eq=False)
+class _Plan:
+    """What a reduced sweep needs that depends only on shapes.  Hashed by
+    identity: ``_plan`` hands out one instance per key, so it rides through
+    ``_init_carries`` as a static argument at no hashing cost."""
+
+    chunk_avals: Scenario
+    res_avals: SimResult
+    reducers: tuple
+    leaf_shardings: tuple | None   # with a mesh: each chunk leaf's sharding
+    rep: object                    # with a mesh: replicated, for the carries
+
+
+@lru_cache(maxsize=None)
+def _plan(treedef, row_avals: tuple, chunk: int, reducers: tuple, mesh,
+          axis: str) -> _Plan:
+    """The sweep's shape plan: chunk avals, ``eval_shape`` of ``simulate``
+    (a trace of the whole engine) and the mesh shardings, computed once per
+    key.  ``row_avals`` drop the campaign axis, so every population size of
+    one grid (the search driver's rungs) shares a plan."""
+    chunk_avals = jax.tree.unflatten(treedef, [
+        jax.ShapeDtypeStruct((chunk,) + s, d) for s, d in row_avals
+    ])
+    res_avals = jax.eval_shape(simulate, chunk_avals)
+    # With a mesh, every fold input's sharding is pinned before each fold
+    # call: otherwise arrays that flow back from a previous fold (search-
+    # driver survivors, the carries themselves) arrive committed to mesh
+    # shardings while fresh chunks arrive uncommitted, and the differing
+    # shardings fork the jit cache per call — the exact hazard simlint R5
+    # probes.
+    leaf_shardings = rep = None
+    if mesh is not None:
+        from repro.dist.sharding import campaign_pspec_tree, named
+
+        leaf_shardings = tuple(jax.tree.leaves(
+            named(mesh, campaign_pspec_tree(chunk_avals, mesh, axis)),
+            is_leaf=lambda x: isinstance(x, jax.sharding.NamedSharding),
+        ))
+        rep = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
+    return _Plan(chunk_avals, res_avals, reducers, leaf_shardings, rep)
+
+
+@partial(jax.jit, static_argnums=0)
+def _init_carries(plan: _Plan):
+    # a fresh set of buffers on every call: the fold donates them
+    return tuple(r.init(plan.chunk_avals, plan.res_avals)
+                 for r in plan.reducers)
+
+
+@partial(jax.jit, static_argnums=1)
+def _finalize(carries, reducers):
+    return tuple(r.finalize(c) for r, c in zip(reducers, carries))
+
+
 def _run_reduced(batched: Scenario, chunk_size: int | None, reduce,
                  mesh, axis: str):
     span = jax.profiler.TraceAnnotation
@@ -282,58 +374,27 @@ def _run_reduced(batched: Scenario, chunk_size: int | None, reduce,
     chunk = chunk_size or n
 
     with span(SPAN_PREPARE):
-        leaves0, treedef = jax.tree.flatten(batched)
-        chunk_avals = jax.tree.unflatten(treedef, [
-            jax.ShapeDtypeStruct((chunk,) + l.shape[1:], l.dtype)
-            for l in leaves0
-        ])
-        res_avals = jax.eval_shape(simulate, chunk_avals)
-        carries = tuple(r.init(chunk_avals, res_avals) for r in reducers)
-
-        # With a mesh, pin every input's sharding before each fold call:
-        # otherwise arrays that flow back from a previous fold (search-driver
-        # survivors, the carries themselves) arrive committed to mesh
-        # shardings while fresh chunks arrive uncommitted, and the differing
-        # shardings fork the jit cache per call — the exact hazard simlint
-        # R5 probes.
-        leaf_shardings = rep = None
-        if mesh is not None:
-            from repro.dist.sharding import campaign_pspec_tree, named
-
-            leaf_shardings = jax.tree.leaves(
-                named(mesh, campaign_pspec_tree(chunk_avals, mesh, axis)),
-                is_leaf=lambda x: isinstance(x, jax.sharding.NamedSharding),
-            )
-            rep = jax.sharding.NamedSharding(
-                mesh, jax.sharding.PartitionSpec())
+        leaves, treedef = jax.tree.flatten(batched)
+        row_avals = tuple((s[1:], d) for s, d in _avals(leaves))
+        plan = _plan(treedef, row_avals, chunk, reducers, mesh, axis)
+        carries = _init_carries(plan)
 
     for lo in range(0, n, chunk):
-        def _slice(x):
-            c = x[lo:lo + chunk]
-            short = chunk - c.shape[0]
-            if short:
-                pad = jnp.broadcast_to(x[-1:], (short,) + x.shape[1:])
-                c = jnp.concatenate([c, pad])
-            return c
-
-        with span(SPAN_SLICE):
-            leaves = tuple(jax.tree.leaves(jax.tree.map(_slice, batched)))
-        if mesh is not None:
-            with span(SPAN_PUT):
-                leaves = tuple(
-                    jax.device_put(l, s)
-                    for l, s in zip(leaves, leaf_shardings)
-                )
-                carries = jax.device_put(carries, rep)
         # (lo, n) ride as one traced i32[2] so every chunk — first, middle,
         # padded tail — reuses the same compiled fold program
+        with span(SPAN_SLICE):
+            part, bounds = _slice_chunk(leaves, lo, chunk)
+        if mesh is not None:
+            with span(SPAN_PUT):
+                part = jax.device_put(part, plan.leaf_shardings)
+                carries, bounds = jax.device_put((carries, bounds),
+                                                 plan.rep)
         with span(SPAN_LAUNCH):
-            bounds = jnp.asarray([lo, n], jnp.int32)
             carries = _run_chunk_fold(
-                leaves, bounds, carries, treedef, reducers, mesh, axis
+                part, bounds, carries, treedef, reducers, mesh, axis
             )
     with span(SPAN_FINALIZE):
-        outs = tuple(r.finalize(c) for r, c in zip(reducers, carries))
+        outs = _finalize(carries, reducers)
     if keys is not None:
         return dict(zip(keys, outs))
     return outs[0] if single else outs
@@ -393,18 +454,13 @@ def run_campaign(
         sharding = named(mesh, campaign_pspec_tree(batched, mesh, axis))
         batched = jax.device_put(batched, sharding)
         return _run_whole_sharded(batched, mesh, axis)
+    leaves, treedef = jax.tree.flatten(batched)
     results = []
     for lo in range(0, n, chunk_size):
-        def _slice(x):
-            c = x[lo:lo + chunk_size]
-            short = chunk_size - c.shape[0]
-            if short:
-                pad = jnp.broadcast_to(x[-1:], (short,) + x.shape[1:])
-                c = jnp.concatenate([c, pad])
-            return c
-
+        part, _ = _slice_chunk(leaves, lo, chunk_size)
         # the chunk is a fresh temporary -> donating it is always safe
-        results.append(_run_chunk(jax.tree.map(_slice, batched), mesh, axis))
+        chunk = jax.tree.unflatten(treedef, part)
+        results.append(_run_chunk(chunk, mesh, axis))
     return jax.tree.map(lambda *xs: jnp.concatenate(xs)[:n], *results)
 
 

@@ -18,9 +18,12 @@ shapes (``jax.eval_shape`` trees — no arrays materialized); ``fold(carry,
 chunk, res, index, valid)`` consumes one ``[chunk]``-leading batch where
 ``index`` holds global row indices and ``valid`` masks the repeated-row
 padding of the trailing chunk; ``finalize(carry)`` converts the carry to the
-user-facing summary.  Reducers are frozen dataclasses, so they are hashable
-and ride through ``jax.jit`` as static arguments — reuse ONE reducer
-instance across calls or the jit cache forks per instance.
+user-facing summary.  All three are traced: ``run_campaign`` builds the
+carries in one jitted program, folds inside the chunk program and runs
+every reducer's ``finalize`` in one jitted program, so each is pure jnp
+that reads no value on the host.  Reducers are frozen dataclasses, so they
+are hashable and ride through ``jax.jit`` as static arguments — reuse ONE
+reducer instance across calls or the jit cache forks per instance.
 
 Determinism and chunk-size invariance
 -------------------------------------

@@ -30,7 +30,9 @@ def test_autoscale_improves_bursty_turnaround():
     autoscaled pool beats the same scenario with the pool disabled, all work
     finishing in both — and both runs are the same compiled program (the
     autoscale flag is traced, no Python branching on load)."""
-    fn = jax.jit(simulate_instrumented)
+    # a fresh callable: jit caches pool per underlying function, so another
+    # test's jax.jit(simulate_instrumented) in this process would count here
+    fn = jax.jit(lambda s: simulate_instrumented(s))
     results = {}
     for name, scn in (
         ("on", scenarios.autoscale_scenario(jax.random.PRNGKey(0))),

@@ -115,3 +115,114 @@ print("SHARDED_OK")
                          text=True, env=env, timeout=600)
     assert out.returncode == 0, out.stderr[-2000:]
     assert "SHARDED_OK" in out.stdout
+
+
+# --------------------------------------------------------------------------
+# the front door's compiled host work: chunk slicing, carry init, finalize
+# --------------------------------------------------------------------------
+
+SLICE_CHUNK = 8
+
+
+def _distinct_rows(n):
+    """``n`` fig4 rows that differ in every row (task length), so a wrong or
+    repeated row shows."""
+    rows = [scenarios.fig4_scenario(i % 2, (i // 2) % 2,
+                                    length_mi=1000.0 + 125.0 * i)
+            for i in range(n)]
+    return stack_scenarios(rows)
+
+
+def _eager_chunk(x, lo, chunk):
+    """The reference: eager slice, then pad by repeating the last row."""
+    c = x[lo:lo + chunk]
+    short = chunk - c.shape[0]
+    if short:
+        pad = jnp.broadcast_to(x[-1:], (short,) + x.shape[1:])
+        c = jnp.concatenate([c, pad])
+    return c
+
+
+@pytest.mark.parametrize("n", [SLICE_CHUNK, 2 * SLICE_CHUNK,
+                               2 * SLICE_CHUNK + 3, SLICE_CHUNK - 5])
+def test_compiled_chunk_slice_matches_eager(n):
+    """Every chunk the slice program returns is the eager slice-and-pad bit
+    for bit, and its bounds are ``(lo, n)``."""
+    import jax
+
+    from repro.core import campaign
+
+    leaves = jax.tree.leaves(_distinct_rows(n))
+    for lo in range(0, n, SLICE_CHUNK):
+        part, bounds = campaign._slice_chunk(leaves, lo, SLICE_CHUNK)
+        assert len(part) == len(leaves)
+        for got, x in zip(part, leaves):
+            want = np.asarray(_eager_chunk(x, lo, SLICE_CHUNK))
+            got = np.asarray(got)
+            assert (got.shape, got.dtype) == (want.shape, want.dtype)
+            assert got.tobytes() == want.tobytes()
+        assert bounds.dtype == jnp.int32
+        assert np.asarray(bounds).tolist() == [lo, n]
+
+
+def test_ragged_reduced_sweep_matches_materialized():
+    """A reduced sweep whose last chunk is short (20 = 2 * 8 + 4) folds to
+    the materialized run's answers: integer folds bitwise, the mean to
+    rounding."""
+    from repro.core.reducers import (ArgBestReducer, HistogramReducer,
+                                     MeanReducer, SumReducer, ValuesReducer)
+
+    n = 20
+    batched = _distinct_rows(n)
+    reduce = {
+        "events": SumReducer("n_events"),
+        "mt": MeanReducer("mean_turnaround"),
+        "hist": HistogramReducer("mean_turnaround", 0.0, 8000.0, bins=16),
+        "best": ArgBestReducer("mean_turnaround"),
+        "vals": ValuesReducer("mean_turnaround", n_slots=n),
+    }
+    ref = run_campaign(batched)
+    out = run_campaign(batched, chunk_size=SLICE_CHUNK, reduce=reduce)
+    mt = np.asarray(ref.mean_turnaround)
+    assert int(out["events"]) == int(np.asarray(ref.n_events).sum())
+    np.testing.assert_array_equal(np.asarray(out["vals"]["values"]), mt)
+    assert bool(out["vals"]["filled"].all())
+    idx = np.clip((mt / 500.0).astype(np.int32), 0, 15)
+    np.testing.assert_array_equal(np.asarray(out["hist"]["counts"]),
+                                  np.bincount(idx, minlength=16))
+    assert int(out["best"]["index"]) == int(np.argmin(mt))
+    assert float(out["best"]["value"]) == mt.min()
+    assert int(out["mt"]["n"]) == n
+    np.testing.assert_allclose(float(out["mt"]["mean"]), mt.mean(), rtol=1e-5)
+
+
+def test_warm_sweep_reuses_every_program_and_keeps_the_grid():
+    """A second sweep of the same shapes hits the prepare cache, compiles
+    nothing (slice, init, finalize and fold programs keep their executable
+    counts) and leaves the grid readable: it was not donated."""
+    import jax
+
+    from repro.core import campaign
+    from repro.core.reducers import ArgBestReducer, SumReducer
+
+    batched = _distinct_rows(2 * SLICE_CHUNK + 3)
+    before = [np.asarray(x).copy() for x in jax.tree.leaves(batched)]
+    reduce = {"events": SumReducer("n_events"),
+              "best": ArgBestReducer("mean_turnaround")}
+    programs = (campaign._slice_program, campaign._init_carries,
+                campaign._finalize, campaign._run_chunk_fold)
+
+    def sweep():
+        return jax.block_until_ready(
+            run_campaign(batched, chunk_size=SLICE_CHUNK, reduce=reduce))
+
+    first = sweep()
+    hits = campaign._plan.cache_info().hits
+    sizes = [p._cache_size() for p in programs]
+    second = sweep()
+    assert campaign._plan.cache_info().hits > hits
+    assert [p._cache_size() for p in programs] == sizes
+    for a, b in zip(jax.tree.leaves(first), jax.tree.leaves(second)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for x, want in zip(jax.tree.leaves(batched), before):
+        np.testing.assert_array_equal(np.asarray(x), want)
