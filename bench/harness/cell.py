@@ -126,11 +126,8 @@ def _traced_window(door, seconds: float):
     try:
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
-        jax.profiler.start_trace(d, profiler_options=opts)
-        try:
+        with jax.profiler.trace(d, profiler_options=opts):
             t0, done = window(door, seconds)
-        finally:
-            jax.profiler.stop_trace()
         paths = glob.glob(os.path.join(d, "**", "*.xplane.pb"), recursive=True)
         space = tr.read_file(paths[0])
     finally:
@@ -156,7 +153,8 @@ def run(bench: dict, cell_name: str, seed: int, seconds: float, traced: bool,
     devices = list(devices if devices is not None else jax.devices())
     used = devices[:cell["chips"]]
 
-    door = traffic.door(config, mix, seed, used)
+    family = spec.family(config)
+    door = traffic.door(family, config, mix, seed, used)
     door.warm()
     setup_s = time.perf_counter() - t_start
 
@@ -205,18 +203,15 @@ def run(bench: dict, cell_name: str, seed: int, seconds: float, traced: bool,
         result_extra["breakdown"] = breakdown(reading)
 
     # the check, once the window has closed and the peak has been read
-    ref = correct.reference(config, door.params)
-    if mix["front_door"] == "simulate":
-        worst, failed = correct.compare_simulate(
-            correct.answers_of_simulate(outputs), ref)
-    else:
-        worst, failed = correct.compare_campaign(
-            correct.answers_of_campaign(outputs), ref, door.params, mix)
+    ref = family.reference(config, door.params)
+    worst, failed = family.compare(family.answers(outputs, mix), ref,
+                                   door.params, mix)
     worst = {k: _finite(float(v)) if isinstance(v, float) else v
              for k, v in worst.items()}
-    checks = correct.check_lines(worst)
-    ok = bool(done) and failed == 0 and all(
-        c["value"] <= c["limit"] for c in checks.values())
+    checks = correct.check_lines(worst, family.LIMITS)
+    failed = int(failed)
+    ok = bool(done and failed == 0 and all(
+        c["value"] <= c["limit"] for c in checks.values()))
     result = {"correct": ok, "attempted": len(done), "failed": failed,
               "metrics": metrics, "device": device, **result_extra,
               "checks": checks}

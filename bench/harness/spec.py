@@ -1,10 +1,12 @@
 """``BENCHMARK.json`` and the files it names, found by name.
 
 A cell names a configuration (``configs[].file``) and a traffic mix
-(``bench/traffic/<traffic>.json``); every metric of a cell is read by
+(``bench/traffic/<traffic>.json``); a configuration names its deployment
+family (``bench/families/<family>.py``: what its rows are, how they are
+drawn, built and checked); every metric of a cell is read by
 ``bench/metrics/<metric>.py`` where it is a per-layer metric.  A later
-change adds a configuration, a mix or a metric by adding files and entries,
-and edits none of these.
+change adds a configuration, a family, a mix or a metric by adding files
+and entries, and edits none of these.
 """
 from __future__ import annotations
 
@@ -15,6 +17,7 @@ from pathlib import Path
 
 BENCH = Path(__file__).resolve().parents[1]
 ROOT = BENCH.parent
+FAMILIES = BENCH / "families"
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -46,13 +49,30 @@ def traffic(name: str) -> dict:
         return json.load(f)
 
 
-def metric_reader(name: str):
-    """The ``read`` function of ``bench/metrics/<name>.py``."""
-    path = BENCH / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
+def _module(path: Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def metric_reader(name: str):
+    """The ``read`` function of ``bench/metrics/<name>.py``."""
+    return _module(BENCH / "metrics" / f"{name}.py", f"bench_metric_{name}").read
+
+
+def family(config: dict):
+    """The deployment family module ``FAMILIES/<family>.py`` that a
+    configuration names; naming none, or one with no file, is an error."""
+    name = config.get("family")
+    if not isinstance(name, str) or not NAME.match(name):
+        raise KeyError(f"configuration {config.get('name')!r} names no "
+                       f"deployment family (\"family\": {name!r})")
+    path = FAMILIES / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"configuration {config.get('name')!r} names "
+                                f"family {name!r}, and there is no {path}")
+    return _module(path, f"bench_family_{name}")
 
 
 def metrics_of(bench: dict, cell_name: str, kind: str) -> list[dict]:

@@ -1,15 +1,14 @@
-"""The one traffic generator, and the two front doors it drives.
+"""The two front doors a traffic mix drives, and what they share.
 
-A traffic mix is a data file (``bench/traffic/<mix>.json``).  From it and
-``--seed`` this module draws each scenario's traced values:
+A traffic mix is a data file (``bench/traffic/<mix>.json``).  The
+harness's own keys are ``front_door``, ``rows``, ``pool``, ``chunk_size``,
+``mesh_devices``, ``reduce`` and ``trace_seconds``; the rest belong to the
+configuration's deployment family (``bench/families/<family>.py``), whose
+``draw`` takes each row's traced values from the mix and ``--seed`` and
+whose ``build_one``/``build_rows`` make the rows on the device.
 
-* ``policies``: the host/VM policy pairs, each given to an equal share of
-  the rows (keys left out take the configuration's value); the seed only
-  orders them, so every seed asks for the same work;
-* ``length_scale``: ``[lo, hi]``, a task-length multiplier per row, uniform;
-
-and drives one front door in a closed loop, one client waiting for each
-answer before it asks the next question:
+Each door is a closed loop, one client waiting for each answer before it
+asks the next question:
 
 * ``"simulate"``: one ``simulate`` call per question, on one compiled
   program, cycling through a pool of ``pool`` scenarios built at set-up in
@@ -24,27 +23,11 @@ from __future__ import annotations
 
 import numpy as np
 
-from bench.harness import deploy
-
 SPANS = ("dispatch", "wait", "run_campaign")
 
 
 def rng_for(seed: int) -> np.random.Generator:
     return np.random.default_rng(int(seed) % (1 << 64))
-
-
-def draw(config: dict, mix: dict, n: int, rng) -> dict:
-    """``host_policy``, ``vm_policy``, ``length_scale`` for ``n`` rows."""
-    pol = config["deployment"]["policy"]
-    pairs = [(deploy.POLICIES[p.get("host_policy", pol["host_policy"])],
-              deploy.POLICIES[p.get("vm_policy", pol["vm_policy"])])
-             for p in mix["policies"]]
-    which = rng.permutation(np.arange(n) % len(pairs))
-    lo, hi = mix["length_scale"]
-    scale = rng.uniform(lo, hi, n) if hi > lo else np.full(n, float(lo))
-    return {"host_policy": np.array([pairs[i][0] for i in which], np.int32),
-            "vm_policy": np.array([pairs[i][1] for i in which], np.int32),
-            "length_scale": scale.astype(np.float32)}
 
 
 def reducers(mix: dict) -> dict:
@@ -68,18 +51,16 @@ def reducers(mix: dict) -> dict:
 class SimulateDoor:
     """One ``simulate`` call per question."""
 
-    def __init__(self, config: dict, mix: dict, seed: int):
+    def __init__(self, family, config: dict, mix: dict, seed: int):
         import jax
 
         from repro.core import simulate
 
         self.rng = rng_for(seed)
-        self.params = draw(config, mix, int(mix["pool"]), self.rng)
-        p = self.params
-        self.pool = [
-            deploy.build_one(config, p["host_policy"][i], p["vm_policy"][i],
-                             p["length_scale"][i], mix["sweep_impl"])
-            for i in range(len(p["length_scale"]))]
+        n = int(mix["pool"])
+        self.params = family.draw(config, mix, n, self.rng)
+        self.pool = [family.build_one(config, self.params, i, mix)
+                     for i in range(n)]
         self.program = jax.jit(simulate).lower(self.pool[0]).compile()
         self.order: list[int] = []
         self.rows_per_call = 1
@@ -115,12 +96,13 @@ class SimulateDoor:
 class CampaignDoor:
     """One ``run_campaign`` sweep per question."""
 
-    def __init__(self, config: dict, mix: dict, seed: int, devices=None):
+    def __init__(self, family, config: dict, mix: dict, seed: int,
+                 devices=None):
         import jax
 
         self.rng = rng_for(seed)
-        self.params = draw(config, mix, int(mix["rows"]), self.rng)
-        self.grid = deploy.build_rows(config, self.params, mix["sweep_impl"])
+        self.params = family.draw(config, mix, int(mix["rows"]), self.rng)
+        self.grid = family.build_rows(config, self.params, mix)
         jax.block_until_ready(self.grid)
         self.chunk = int(mix["chunk_size"])
         self.reduce = reducers(mix)
@@ -174,9 +156,11 @@ class CampaignDoor:
         return float(per.sum(0).mean()) * len(outputs)
 
 
-def door(config: dict, mix: dict, seed: int, devices=None):
+def door(family, config: dict, mix: dict, seed: int, devices=None):
+    """The mix's front door over rows of ``config`` that ``family`` draws
+    and builds."""
     if mix["front_door"] == "simulate":
-        return SimulateDoor(config, mix, seed)
+        return SimulateDoor(family, config, mix, seed)
     if mix["front_door"] == "run_campaign":
-        return CampaignDoor(config, mix, seed, devices)
+        return CampaignDoor(family, config, mix, seed, devices)
     raise ValueError(f"unknown front door {mix['front_door']!r}")

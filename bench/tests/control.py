@@ -5,7 +5,7 @@ program's place and compared as the program is.
     python bench/tests/control.py fig4.sweep 1 2 3
 
 prints the numbers compared, per seed, at the cell's own size; the tests
-run it at a small size.  It runs no JAX and touches no chip.
+run it at a small size.  It computes nothing with JAX and touches no chip.
 """
 from __future__ import annotations
 
@@ -42,21 +42,21 @@ def readings(config: dict, mix: dict, seed: int) -> dict:
     """The numbers the check compares, with the control as the program."""
     import ml_dtypes
 
-    from bench.harness import correct, traffic
+    from bench.harness import spec, traffic
 
+    family = spec.family(config)
     rng = traffic.rng_for(seed)
     n = int(mix["pool"] if mix["front_door"] == "simulate" else mix["rows"])
-    params = traffic.draw(config, mix, n, rng)
-    ref = correct.reference(config, params)
-    ctl = correct.reference(config, params, dtype=ml_dtypes.bfloat16)
+    params = family.draw(config, mix, n, rng)
+    ref = family.reference(config, params)
+    ctl = family.reference(config, params, dtype=ml_dtypes.bfloat16)
     if mix["front_door"] == "simulate":
         answers = [(i, {k: ctl[k][i] for k in
                         ("start_t", "finish_t", "n_finished", "n_events")})
                    for i in range(n)]
-        worst, _ = correct.compare_simulate(answers, ref)
     else:
-        worst, _ = correct.compare_campaign([_folded(ctl, params, mix)], ref,
-                                            params, mix)
+        answers = [_folded(ctl, params, mix)]
+    worst, _ = family.compare(answers, ref, params, mix)
     return worst
 
 

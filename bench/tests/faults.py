@@ -15,19 +15,13 @@ import time
 
 
 def small(bench, cell_name):
-    """The cell's configuration and mix at a size a test run holds."""
+    """The cell's configuration and mix at the size its family's CPU tests
+    run at."""
     from bench.harness import spec
 
     cell = spec.cell(bench, cell_name)
     cfg = spec.config(bench, cell["config"])
-    mix = spec.traffic(cell["traffic"])
-    if cfg["name"] == "fig9_10":
-        dep = cfg["deployment"]
-        dep["hosts"]["count"], dep["vms"]["count"] = 200, 10
-        dep["tasks"]["count"], dep["tasks"]["group_size"] = 40, 10
-    if mix["front_door"] == "run_campaign":
-        mix.update(rows=128, chunk_size=64)
-    return cfg, mix
+    return spec.family(cfg).small(cfg, spec.traffic(cell["traffic"]))
 
 
 @contextlib.contextmanager

@@ -15,6 +15,10 @@ ONE_CHIP = [
     ("fig4.sweep", "state_unchanged"),
     ("fig4.sweep", "half_batch"),
     ("fig4.sweep", "answer_altered"),
+    ("fig9_10.sweep", "none"),
+    ("fig9_10.sweep", "state_unchanged"),
+    ("fig9_10.sweep", "half_batch"),
+    ("fig9_10.sweep", "answer_altered"),
 ]
 
 

@@ -7,7 +7,7 @@ import jax
 import numpy as np
 import pytest
 
-from bench.harness import correct, deploy, traffic
+from bench.harness import spec, traffic
 from bench.harness.spec import BENCH
 from bench.reference import fold, sim
 from repro.core import simulate
@@ -25,12 +25,11 @@ def _mix(name):
         return json.load(f)
 
 
-def _small_fig9_10(hosts=200, vms=10, groups=4):
-    cfg = _config("fig9_10")
-    dep = cfg["deployment"]
-    dep["hosts"]["count"], dep["vms"]["count"] = hosts, vms
-    dep["tasks"]["count"], dep["tasks"]["group_size"] = vms * groups, vms
-    return cfg
+TASKS = spec.family(_config("fig4"))
+
+
+def _small_fig9_10():
+    return TASKS.small(_config("fig9_10"), {"front_door": "simulate"})[0]
 
 
 def _params(hp, vp, scale):
@@ -47,7 +46,7 @@ def _params(hp, vp, scale):
 ])
 def test_fig4_analytic_finish_times(hp, vp, times, events):
     """Figure 4 (a)-(d): L = 4000 MI / 10 MIPS = 400 s per task."""
-    ref = correct.reference(_config("fig4"), _params([hp], [vp], [1.0]))
+    ref = TASKS.reference(_config("fig4"), _params([hp], [vp], [1.0]))
     np.testing.assert_allclose(np.unique(ref["finish_t"][0]), times, rtol=1e-12)
     assert ref["n_finished"][0] == 8
     assert ref["n_events"][0] == events and ref["event_slack"][0] == 0
@@ -56,7 +55,7 @@ def test_fig4_analytic_finish_times(hp, vp, times, events):
 def test_fig9_10_space_shared_tasks_take_1200_s():
     """Section 5: dedicated cores, so every 1.2e6 MI task runs exactly 1200 s,
     and the 10 groups queue on each VM: the last finishes at 12,000.003 s."""
-    ref = correct.reference(_config("fig9_10"), _params([0], [0], [1.0]))
+    ref = TASKS.reference(_config("fig9_10"), _params([0], [0], [1.0]))
     run = ref["finish_t"][0] - ref["start_t"][0]
     np.testing.assert_allclose(run, 1200.0, rtol=1e-12)
     assert ref["n_finished"][0] == 500
@@ -64,7 +63,7 @@ def test_fig9_10_space_shared_tasks_take_1200_s():
 
 
 def test_fig9_10_time_shared_slows_every_task():
-    ref = correct.reference(_config("fig9_10"), _params([0], [1], [1.0]))
+    ref = TASKS.reference(_config("fig9_10"), _params([0], [1], [1.0]))
     assert ref["n_finished"][0] == 500
     assert (ref["finish_t"][0] - ref["start_t"][0] >= 1200.0 - 1e-9).all()
     assert ref["mean_turnaround"][0] > 1200.0
@@ -74,14 +73,13 @@ def test_simulate_matches_reference_on_fig9_10():
     """Both VM policies and drawn task lengths, through ``simulate``."""
     cfg = _small_fig9_10()
     p = _params([0, 0, 0, 0], [0, 1, 0, 1], [1.0, 1.0, 1.17, 1.43])
-    ref = correct.reference(cfg, p)
+    ref = TASKS.reference(cfg, p)
     outs = []
     for i in range(4):
-        scn = deploy.build_one(cfg, p["host_policy"][i], p["vm_policy"][i],
-                               p["length_scale"][i])
+        scn = TASKS.build_one(cfg, p, i, {"sweep_impl": "jnp"})
         outs.append((i, jax.jit(simulate)(scn)))
-    worst, failed = correct.compare_simulate(
-        correct.answers_of_simulate(outs), ref)
+    worst, failed = TASKS.compare_simulate(
+        TASKS.answers(outs, {"front_door": "simulate"}), ref)
     assert failed == 0, worst
     assert worst["time_err"] < 1e-6
 
@@ -93,23 +91,23 @@ def test_run_campaign_fold_matches_reference_on_fig4(mesh_devices):
     cfg = _config("fig4")
     mix = _mix("sweep_n16384")
     mix.update(rows=96, chunk_size=32, mesh_devices=mesh_devices)
-    door = traffic.CampaignDoor(cfg, mix, seed=2**31 + 11)
+    door = traffic.CampaignDoor(TASKS, cfg, mix, seed=2**31 + 11)
     _, out = door.call()
-    ref = correct.reference(cfg, door.params)
-    worst, failed = correct.compare_campaign(
-        correct.answers_of_campaign([(0, out)]), ref, door.params, mix)
+    ref = TASKS.reference(cfg, door.params)
+    worst, failed = TASKS.compare_campaign(
+        TASKS.answers([(0, out)], mix), ref, door.params, mix)
     assert failed == 0, worst
     assert door.iterations([(0, out)]) == 3 * 4      # 3 chunks, 4 events max
 
 
 def test_draw_gives_every_seed_the_same_work():
     cfg, mix = _config("fig4"), _mix("sweep_n16384")
-    a = traffic.draw(cfg, mix, 1000, traffic.rng_for(5))
-    b = traffic.draw(cfg, mix, 1000, traffic.rng_for(2**31 + 99))
+    a = TASKS.draw(cfg, mix, 1000, traffic.rng_for(5))
+    b = TASKS.draw(cfg, mix, 1000, traffic.rng_for(2**31 + 99))
     pairs = lambda d: sorted(zip(d["host_policy"].tolist(), d["vm_policy"].tolist()))
     assert pairs(a) == pairs(b)
     assert not np.array_equal(a["host_policy"], b["host_policy"])
-    c = traffic.draw(cfg, mix, 1000, traffic.rng_for(5))
+    c = TASKS.draw(cfg, mix, 1000, traffic.rng_for(5))
     assert all(np.array_equal(a[k], c[k]) for k in a)
 
 
@@ -147,22 +145,19 @@ def test_control_in_bfloat16_fails_the_time_limit():
 
     cfg = _small_fig9_10()
     p = _params([0] * 4, [0, 1, 0, 1], [1.0, 1.0, 1.17, 1.43])
-    ref = correct.reference(cfg, p)
-    ctl = correct.reference(cfg, p, dtype=ml_dtypes.bfloat16)
+    ref = TASKS.reference(cfg, p)
+    ctl = TASKS.reference(cfg, p, dtype=ml_dtypes.bfloat16)
     answers = [(i, {k: ctl[k][i] for k in ("start_t", "finish_t",
                                             "n_finished", "n_events")})
                for i in range(4)]
-    worst, failed = correct.compare_simulate(answers, ref)
+    worst, failed = TASKS.compare_simulate(answers, ref)
     assert failed > 0
-    assert worst["time_err"] > 10 * correct.LIMITS["time_err"]
-
+    assert worst["time_err"] > 10 * TASKS.LIMITS["time_err"]
 
 
 def test_control_in_bfloat16_fails_the_sweep_check():
     """The control's folded answers over a drawn grid, at a small size."""
     import control
-
-    from bench.harness import spec
 
     bench = spec.load()
     w = spec.cell(bench, "fig4.sweep")
