@@ -165,12 +165,24 @@ class LintContext:
     @staticmethod
     def _with_topology(scn):
         """Attach a 1-DC uniform topology so the transfer phase
-        (step.SCOPE_TRANSFER) exists in every linted program — all lint
-        scenarios carry it, keeping R5's structure-identity probe intact."""
-        import dataclasses
+        (step.SCOPE_TRANSFER) exists in every linted program, and a small
+        power-aware consolidation so the consolidation phase
+        (step.SCOPE_CONSOLIDATE) does — all lint scenarios carry both,
+        keeping R5's structure-identity probe intact."""
+        import numpy as np
 
+        from repro.core.consolidate import Consolidation, ConsolidationPolicy
         from repro.core.energy import Topology
-        return dataclasses.replace(scn, topology=Topology.uniform(1))
+
+        cap = np.asarray(scn.hosts.cores * scn.hosts.mips)
+        consolidation = Consolidation.build(
+            jnp.full((scn.vms.n_vms, 4), 50, jnp.int32),
+            np.zeros(cap.shape, np.int64),
+            [[86, 89.4, 92.6, 96, 99.5, 102, 106, 108, 112, 114, 117]], cap,
+            ConsolidationPolicy(detector=jnp.int32(0),
+                                param=jnp.float32(0.8)), 100.0)
+        return dataclasses.replace(scn, topology=Topology.uniform(1),
+                                   dynamic_consolidation=consolidation)
 
     def scenario(self, **kw):
         """The canonical single-scenario lint subject (paper Figure 4)."""

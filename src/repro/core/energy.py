@@ -3,8 +3,10 @@ implemented (§6: "power consumption, heat dissipation", "BRITE topology").
 
 Power model (linear-in-utilization, the standard DVFS-era datacenter model):
     P(host) = P_idle + (P_peak - P_idle) * utilization
-integrated over the piecewise-constant event intervals the engine already
-produces, so per-DC energy falls out of the same sweep that advances work.
+or, per host, a table of watts at evenly spaced utilizations, linear between
+its points (``table_watts``), integrated over the piecewise-constant event
+intervals the engine already produces, so per-DC energy falls out of the
+same sweep that advances work.
 
 Topology model: an inter-DC latency/bandwidth matrix (BRITE-style edge
 parameters without the generator) replacing the paper's single scalar
@@ -24,16 +26,23 @@ from repro.core.pytree import pytree_dataclass
 
 @pytree_dataclass
 class PowerModel:
-    """Per-DC host power parameters, [D] each.
+    """Host power parameters, in one of two forms.
+
+    Linear: ``watts_idle`` and ``watts_peak``, [D] each, idle to peak in
+    proportion to utilization.  Table: ``table``, [D, H, P] watts of each
+    host at P evenly spaced utilizations from 0% to 100%, linear between
+    them (``table_watts``; the 11-point SPECpower tables of Beloglazov &
+    Buyya's consolidation study, DESIGN.md §15).
 
     ``gate_idle`` models per-host power gating: a host with *no* VM holding
-    resources on it draws zero instead of ``watts_idle`` — the accounting
+    resources on it draws zero instead of its idle power — the accounting
     that makes energy-consolidation migration (DESIGN.md §8) visible.  None
     (or all-False) keeps the classic always-on datacenter model.
     """
-    watts_idle: Array    # drawn whenever a host is powered
-    watts_peak: Array    # at 100% core-MIPS utilization
-    gate_idle: Array | None = None   # [D] bool: unoccupied hosts draw 0
+    watts_idle: Array | None = None   # drawn whenever a host is powered
+    watts_peak: Array | None = None   # at 100% core-MIPS utilization
+    gate_idle: Array | None = None    # [D] bool: unoccupied hosts draw 0
+    table: Array | None = None        # [D, H, P] watts at 0%..100%
 
     @staticmethod
     def uniform(n_dc: int, idle: float = 93.0, peak: float = 135.0,
@@ -44,6 +53,27 @@ class PowerModel:
             watts_peak=jnp.full((n_dc,), peak, jnp.float32),
             gate_idle=jnp.full((n_dc,), gate_idle, bool),
         )
+
+    @staticmethod
+    def from_tables(host_class, class_watts) -> "PowerModel":
+        """The table form from each host's class (``[D, H]`` ints) and the
+        classes' tables (``[classes, P]`` watts)."""
+        watts = np.asarray(class_watts, np.float64)
+        return PowerModel(table=jnp.asarray(
+            watts[np.asarray(host_class, np.int64)], jnp.float32))
+
+
+def table_watts(table: Array, util: Array) -> Array:
+    """Watts at ``util`` (clipped to [0, 1]) from tables of P evenly spaced
+    points (``[..., P]``, one per entry of ``util``), linear between them.
+    The points are picked by one-hot sums, not gathers."""
+    P = table.shape[-1]
+    x = (P - 1) * jnp.clip(util, 0.0, 1.0)
+    i = jnp.minimum(jnp.floor(x), P - 2).astype(jnp.int32)
+    pts = jnp.arange(P, dtype=jnp.int32)
+    wi = jnp.sum(jnp.where(pts == i[..., None], table, 0.0), axis=-1)
+    wj = jnp.sum(jnp.where(pts == i[..., None] + 1, table, 0.0), axis=-1)
+    return wi + (wj - wi) * (x - i.astype(jnp.float32))
 
 
 @pytree_dataclass
@@ -167,19 +197,24 @@ def power_draw(
     """
     util = host_utilization(scn, state, vm_mips)
     pm: PowerModel = scn.power            # type: ignore[attr-defined]
-    idle = jnp.broadcast_to(
-        pm.watts_idle[:, None], scn.hosts.cores.shape
-    )
-    if getattr(pm, "gate_idle", None) is not None:
-        idle = jnp.where(
-            pm.gate_idle[:, None] & ~host_occupied(scn, state), 0.0, idle
+    gated = getattr(pm, "gate_idle", None) is not None
+    if pm.table is None:
+        idle = jnp.broadcast_to(
+            pm.watts_idle[:, None], scn.hosts.cores.shape
         )
+        if gated:
+            idle = jnp.where(
+                pm.gate_idle[:, None] & ~host_occupied(scn, state), 0.0, idle
+            )
+        draw = idle + (pm.watts_peak - pm.watts_idle)[:, None] * util
+    else:
+        draw = table_watts(pm.table, util)
+        if gated:
+            draw = jnp.where(
+                pm.gate_idle[:, None] & ~host_occupied(scn, state), 0.0, draw
+            )
     # a failed host draws nothing — it is off, not idling (DESIGN.md §9)
-    watts = jnp.where(
-        scn.hosts.exists & state.host_up,
-        idle + (pm.watts_peak - pm.watts_idle)[:, None] * util,
-        0.0,
-    )
+    watts = jnp.where(scn.hosts.exists & state.host_up, draw, 0.0)
     return jnp.sum(watts, axis=1)
 
 

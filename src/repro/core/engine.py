@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 from jax import Array
 
+from repro.core import consolidate
 from repro.core import step as step_mod
 from repro.core.entities import (
     INF,
@@ -119,6 +120,8 @@ def init_state(scn: Scenario) -> SimState:
         cl_xfer_dst=jnp.full((C,), -1, i32),
         cl_xfer_rem=jnp.zeros((C,), f32),
         cl_xfer_share=jnp.zeros((C,), f32),
+        consol=(None if scn.dynamic_consolidation is None
+                else consolidate.init_power_state(scn)),
     )
 
 

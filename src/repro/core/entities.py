@@ -238,6 +238,13 @@ class Scenario:
     checkpoint rollback, SLA/downtime accounting (DESIGN.md §9) — and
     likewise changes nothing when None.
 
+    ``dynamic_consolidation`` (``core/consolidate.py``) attaches CloudSim's
+    power-aware dynamic consolidation: per-VM utilisation series, per-host
+    power tables and an overload detector, with a detect/select/place pass
+    at every scheduling tick (DESIGN.md §15); None changes nothing.  It is
+    not ``scenarios.consolidation_scenario``, which drains a datacenter
+    through ``step.MigrationInstrument`` (DESIGN.md §8).
+
     ``instruments`` holds *extra* step.Instrument observables, threaded
     through the event loop after the defaults (sensor, market, energy); their
     array fields are traced data, so campaigns may vmap over them.
@@ -252,6 +259,8 @@ class Scenario:
     topology: object = None     # energy.Topology | None
     outages: object = None      # Outages | None — per-host failure schedule
     instruments: tuple = ()     # tuple[step.Instrument, ...] extra observables
+    dynamic_consolidation: object = None  # consolidate.Consolidation | None
+                                          # — the per-tick PABFD pass (§15)
     max_steps: int = 0          # 0 -> derived bound (see step.default_max_steps)
     sweep_impl: str = "jnp"     # "jnp" | "pallas" — advance-sweep implementation
 
@@ -334,6 +343,8 @@ class SimState:
     cl_xfer_dst: Array   # [C] i32 destination DC of the cloudlet's in-flight
     cl_xfer_rem: Array   # [C] f32   stage-in transfer (-1 / MB / Mbps,
     cl_xfer_share: Array # [C] f32   mirroring the VM transfer columns)
+    # --- power-aware consolidation (None unless Scenario.dynamic_consolidation) ---
+    consol: object = None  # consolidate.PowerState | None
 
 
 @pytree_dataclass
@@ -372,6 +383,8 @@ class SimResult:
     ttft_p99: Array        # scalar f32: p99 time-to-first-token
     tpot_p50: Array        # scalar f32: median time-per-output-token
     tpot_p99: Array        # scalar f32: p99 time-per-output-token
+    # --- power-aware consolidation (None unless Scenario.dynamic_consolidation) ---
+    power: object = None   # consolidate.PowerResult | None
 
 
 def finished_mask(res: SimResult) -> Array:

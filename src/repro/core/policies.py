@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from jax import Array
 
 from repro.core.entities import INF, TIME_SHARED, Scenario, SimState
-from repro.core import segments
+from repro.core import consolidate, segments
 
 
 def cloudlet_ready(scn: Scenario, state: SimState) -> Array:
@@ -120,6 +120,9 @@ def vm_demand_mips(scn: Scenario, state: SimState) -> Array:
 
 def host_level_mips(scn: Scenario, state: SimState) -> Array:
     """[V] f32 — total MIPS each VM is granted by its host right now."""
+    if scn.dynamic_consolidation is not None:
+        # demand follows each VM's utilisation series (DESIGN.md §15)
+        return consolidate.vm_grant(scn, state)
     hosts, vms = scn.hosts, scn.vms
     D, H = hosts.cores.shape
     n_seg = D * H

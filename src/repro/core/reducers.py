@@ -48,17 +48,29 @@ from repro.core.entities import INF, Scenario, SimResult
 
 
 def _metric_fn(metric):
-    """Normalize a metric spec: a ``SimResult`` field name or a callable
+    """Normalize a metric spec: a ``SimResult`` field name, a dotted path
+    into an optional result group (``"power.esv"``), or a callable
     ``SimResult -> [B]`` array (one scalar per scenario row)."""
     if callable(metric):
         return metric
     if isinstance(metric, str):
-        if metric not in {f.name for f in dataclasses.fields(SimResult)}:
+        head, *rest = metric.split(".")
+        if head not in {f.name for f in dataclasses.fields(SimResult)}:
             raise ValueError(
                 f"unknown SimResult field {metric!r}; pass a callable for "
                 "derived metrics"
             )
-        return lambda res: getattr(res, metric)
+
+        def get(res):
+            out = getattr(res, head)
+            for name in rest:
+                if out is None or not hasattr(out, name):
+                    raise ValueError(
+                        f"{metric!r}: this campaign's results have no {name!r}")
+                out = getattr(out, name)
+            return out
+
+        return get
     raise TypeError(f"metric must be a field name or callable, got {metric!r}")
 
 

@@ -6,6 +6,9 @@ event batch:
 
     0. host failure/repair edges     (outage schedule: evict + roll back)
     1. instrument ``pre`` hooks      (Sensor tick lives here)
+       consolidation pass            (at scheduling ticks, when
+                                      ``Scenario.dynamic_consolidation``
+                                      is set)
     2. VM lifecycle                  (release drained, place due requests)
     3. policy sweep                  (per-cloudlet MIPS rates)
     4. next-event bound              (ready / request / migration / failure /
@@ -31,7 +34,7 @@ import jax
 import jax.numpy as jnp
 from jax import Array
 
-from repro.core import kvserve, policies, provision, segments
+from repro.core import consolidate, kvserve, policies, provision, segments
 from repro.kernels import ops as _kernel_ops
 from repro.core.entities import (
     INF,
@@ -64,9 +67,12 @@ SCOPE_PROVISION = "phase_provision"
 SCOPE_DISPATCH = "phase_dispatch"
 SCOPE_TRANSFER = "phase_transfer"
 SCOPE_SERVING = "phase_serving"
-# SCOPE_TRANSFER only exists in programs traced with a topology attached;
-# simlint's lint scenarios carry one so R1 covers all four phases.
-PHASE_SCOPES = (SCOPE_PROVISION, SCOPE_DISPATCH, SCOPE_TRANSFER, SCOPE_SERVING)
+SCOPE_CONSOLIDATE = "phase_consolidate"
+# SCOPE_TRANSFER and SCOPE_CONSOLIDATE only exist in programs traced with a
+# topology or a consolidation attached; simlint's lint scenarios carry both
+# so R1 covers every phase.
+PHASE_SCOPES = (SCOPE_PROVISION, SCOPE_DISPATCH, SCOPE_TRANSFER, SCOPE_SERVING,
+                SCOPE_CONSOLIDATE)
 
 # Named scopes over the rest of the program, so a profiler trace assigns
 # nearly every device op to a phase (bench/metrics reads them by name).  They
@@ -106,6 +112,8 @@ def default_max_steps(scn: Scenario) -> int:
         # row, and fair-share recomputes can split previously-coincident
         # completions into separate events
         extra += 2 * scn.cloudlets.n_cloudlets
+    if scn.dynamic_consolidation is not None:
+        extra += scn.dynamic_consolidation.n_ticks
     return 4 * (scn.cloudlets.n_cloudlets + scn.vms.n_vms) + 260 + extra
 
 
@@ -761,6 +769,24 @@ def init_aux(scn: Scenario, extra_instruments: tuple = ()) -> tuple:
     )
 
 
+def _check_dynamic_consolidation(scn: Scenario, instruments: tuple) -> None:
+    """A dynamic consolidation keeps its own power accounting and moves VMs
+    outside the provisioner's ``free_*`` ledger, so it rejects what would
+    count energy twice (``Scenario.power``) or read a stale ledger
+    (outages, autoscaling, live migration)."""
+    if scn.dynamic_consolidation is None:
+        return
+    clash = [name for name, on in (("power", scn.power is not None),
+                                   ("outages", scn.outages is not None))
+             if on]
+    clash += [ins.name for ins in instruments
+              if ins.name in ("autoscale", "migration")]
+    if clash:
+        raise ValueError(
+            f"a dynamic consolidation does not combine with {clash}: it "
+            "accounts its own energy and keeps no free_* ledger")
+
+
 def make_context(
     scn: Scenario, extra_instruments: tuple = ()
 ) -> tuple[StepContext, tuple]:
@@ -770,6 +796,7 @@ def make_context(
     extras — is the accrual order inside each step.
     """
     instruments = instruments_for(scn, extra_instruments)
+    _check_dynamic_consolidation(scn, instruments)
     names = [ins.name for ins in instruments]
     dupes = {n for n in names if names.count(n) > 1}
     if dupes:
@@ -833,6 +860,10 @@ def _phase_prologue(
     if scn.topology is not None:
         st = provision.settle_transfers(scn, st)
 
+    # --- consolidation moves whose transfer ended land on their host ---
+    if scn.dynamic_consolidation is not None:
+        st = consolidate.settle_landings(scn, st)
+
     # --- instrument pre hooks (Sensor tick refreshes sensed_load) ---
     aux = list(aux)
     for i, ins in enumerate(instruments):
@@ -850,6 +881,8 @@ def _cand_kinds(scn: Scenario, instruments: tuple) -> Array:
     cand_k = [K_READY, K_READY, K_VM_REQUEST, K_MIGRATION, K_SERVING]
     if scn.topology is not None:
         cand_k.append(K_STAGE)
+    if scn.dynamic_consolidation is not None:
+        cand_k.append(K_TICK)
     if scn.outages is not None:
         cand_k += [K_FAILURE, K_REPAIR]
     cand_k += [ins.bound_kind for ins in instruments]
@@ -896,6 +929,8 @@ def _phase_bound(
             & (cls.submit_t > st.t)
         )
         cand_t.append(_min_where(cls.submit_t, staging))
+    if scn.dynamic_consolidation is not None:
+        cand_t.append(consolidate.next_tick(scn, st))
     if scn.outages is not None:
         ex = scn.hosts.exists
         cand_t.append(jnp.min(jnp.where(
@@ -999,6 +1034,18 @@ def event_step(
 
     with jax.named_scope(SCOPE_PROLOGUE):
         st, aux = _phase_prologue(scn, st, aux, instruments)
+
+    # --- the consolidation pass, at scheduling ticks only (DESIGN.md §15) ---
+    if scn.dynamic_consolidation is not None:
+        with jax.named_scope(SCOPE_CONSOLIDATE):
+            due = consolidate.tick_due(scn, st)
+            first = st.consol.k == 0
+            st = jax.lax.cond(
+                due & first, lambda s: consolidate.initial_pass(scn, s),
+                lambda s: s, st)
+            st = jax.lax.cond(
+                due & ~first, lambda s: consolidate.tick_pass(scn, s),
+                lambda s: s, st)
 
     # --- VM placement + broker dispatch, skipped when nothing is due ---
     with jax.named_scope(SCOPE_PROVISION):
@@ -1119,6 +1166,21 @@ def batch_event_step(
 
     with jax.named_scope(SCOPE_PROLOGUE):
         st1, aux1 = jax.vmap(prologue)(scn_b, st_b, aux_b)
+
+    if scn_b.dynamic_consolidation is not None:
+        with jax.named_scope(SCOPE_CONSOLIDATE):
+            due = jax.vmap(consolidate.tick_due)(scn_b, st1) & live
+            first = st1.consol.k == 0
+            for fn, rows in ((consolidate.initial_pass, due & first),
+                             (consolidate.tick_pass, due & ~first)):
+                st1 = jax.lax.cond(
+                    jnp.any(rows),
+                    lambda s, fn=fn, rows=rows: jax.vmap(
+                        lambda scn, st, p: consolidate.gated(fn, p, scn, st)
+                    )(scn_b, s, rows),
+                    lambda s: s,
+                    st1,
+                )
 
     # --- VM placement + broker dispatch: batch-global skip predicates ---
     with jax.named_scope(SCOPE_PROVISION):
@@ -1265,6 +1327,8 @@ def finalize_result(scn: Scenario, st: SimState) -> SimResult:
         ttft_p99=_masked_pct(ttft, sfin, 0.99),
         tpot_p50=_masked_pct(tpot, sfin, 0.50),
         tpot_p99=_masked_pct(tpot, sfin, 0.99),
+        power=(None if scn.dynamic_consolidation is None
+               else consolidate.finalize(scn, st)),
     )
 
 

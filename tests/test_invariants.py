@@ -263,9 +263,12 @@ def test_neutral_topology_is_bitwise_flat(name, scn):
             err_msg=f"{name}: SimResult.{f.name} diverged (trace)")
     res_b = jax.jit(simulate)(stack_scenarios([scn_t, scn_t]))
     for f in dataclasses.fields(res):
+        a, b = getattr(res, f.name), getattr(res_b, f.name)
+        if a is None:   # an optional result group (SimResult.power) not attached
+            assert b is None, f"{name}: SimResult.{f.name} appeared (batch-major)"
+            continue
         np.testing.assert_array_equal(
-            np.array(getattr(res, f.name)),
-            np.array(getattr(res_b, f.name))[0],
+            np.array(a), np.array(b)[0],
             err_msg=f"{name}: SimResult.{f.name} diverged (batch-major)")
 
 
