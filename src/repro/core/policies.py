@@ -175,7 +175,7 @@ def cloudlet_rates(scn: Scenario, state: SimState) -> tuple[Array, Array]:
 
     # The effective binding: fixed rows carry their Cloudlets.vm from init,
     # service rows the broker's dispatch choice (undispatched rows are not
-    # ready, so the clipped gather below never grants them capacity).
+    # ready, so the clipped lookup below never grants them capacity).
     vmi = jnp.clip(state.cl_vm, 0, V - 1)
 
     ready = cloudlet_ready(scn, state)
@@ -193,20 +193,11 @@ def cloudlet_rates(scn: Scenario, state: SimState) -> tuple[Array, Array]:
 
     percore_capacity = vm_mips / vm_cores_f              # [V] MIPS per granted core
 
-    # --- space-shared inside the VM (Fig 4a/b upper): FCFS core occupancy ---
-    prefix = segments.segment_prefix_sum(
-        jnp.where(occ_leg, cl_cores_f, 0.0), seg, V)
-    fits = prefix + cl_cores_f <= vms.cores[vmi].astype(jnp.float32) + 1e-6
-    space = jnp.where(occ_leg & fits, percore_capacity[vmi], 0.0)
-
     # --- time-shared inside the VM (Fig 4b/d): equal per-core share ---
     total_demand = segments.segment_sum(
         jnp.where(occ_leg, cl_cores_f, 0.0), seg, V)
     denom = jnp.maximum(total_demand, vms.cores.astype(jnp.float32))
     share = vm_mips / jnp.maximum(denom, 1e-9)           # per demanded core
-    time = jnp.where(occ_leg, share[vmi], 0.0)
-
-    rate = jnp.where(scn.policy.vm_policy == TIME_SHARED, time, space)
 
     # --- continuous-batching decode (DESIGN.md §14) ---
     # An admitted serving row decodes as a member of its VM's batch: per-step
@@ -217,9 +208,22 @@ def cloudlet_rates(scn: Scenario, state: SimState) -> tuple[Array, Array]:
     seg_srv = jnp.where(occ_srv, vmi, V)
     batch = segments.segment_sum(occ_srv.astype(jnp.float32), seg_srv, V)
     slow = 1.0 + scn.policy.batch_degradation * jnp.maximum(batch - 1.0, 0.0)
-    srv_rate = percore_capacity[vmi] / jnp.maximum(slow[vmi], 1e-9)
+
+    # every per-VM quantity at each cloudlet's VM, read through one mask
+    cores_c, percore_c, share_c, slow_c, mips_c = segments.lookup(
+        (vms.cores, percore_capacity, share, slow, vm_mips), vmi)
+
+    # --- space-shared inside the VM (Fig 4a/b upper): FCFS core occupancy ---
+    prefix = segments.segment_prefix_sum(
+        jnp.where(occ_leg, cl_cores_f, 0.0), seg, V)
+    fits = prefix + cl_cores_f <= cores_c.astype(jnp.float32) + 1e-6
+    space = jnp.where(occ_leg & fits, percore_c, 0.0)
+    time = jnp.where(occ_leg, share_c, 0.0)
+    rate = jnp.where(scn.policy.vm_policy == TIME_SHARED, time, space)
+
+    srv_rate = percore_c / jnp.maximum(slow_c, 1e-9)
     rate = jnp.where(is_serving, jnp.where(occ_srv, srv_rate, 0.0), rate)
 
     # A cloudlet only runs while its VM is granted capacity.
-    rate = jnp.where(vm_mips[vmi] > 0, rate, 0.0)
+    rate = jnp.where(mips_c > 0, rate, 0.0)
     return rate, vm_mips

@@ -147,7 +147,8 @@ def _min_where(x: Array, mask: Array) -> Array:
 def _done_or_doomed(scn: Scenario, st: SimState) -> Array:
     fin = policies.cloudlet_finished(st)
     assigned = st.cl_vm >= 0
-    doomed = assigned & st.vm_failed[jnp.clip(st.cl_vm, 0, scn.vms.n_vms - 1)]
+    doomed = assigned & segments.lookup(
+        st.vm_failed, jnp.clip(st.cl_vm, 0, scn.vms.n_vms - 1))
     return fin | doomed | ~scn.cloudlets.exists
 
 
@@ -291,15 +292,16 @@ class MarketInstrument(Instrument):
         cls = scn.cloudlets
         # Bill against the dispatched assignment (== cls.vm for fixed rows);
         # unassigned rows are never active and never hit an IO edge.
-        dc_of_cl = st.vm_dc[jnp.clip(st.cl_vm, 0, scn.vms.n_vms - 1)]
-        run_cost = jnp.where(
-            ev.active, ev.dt * scn.market.cost_per_cpu_sec[dc_of_cl], 0.0
-        )
+        dc_of_cl = segments.lookup(
+            st.vm_dc, jnp.clip(st.cl_vm, 0, scn.vms.n_vms - 1))
+        dc_seg = jnp.clip(dc_of_cl, 0, scn.hosts.n_dc - 1)
+        cpu_price, bw_price = segments.lookup(
+            (scn.market.cost_per_cpu_sec, scn.market.cost_per_bw_mb), dc_seg)
+        run_cost = jnp.where(ev.active, ev.dt * cpu_price, 0.0)
         io_mb = jnp.where(ev.newly_started, cls.input_mb, 0.0) + jnp.where(
             ev.newly_finished, cls.output_mb, 0.0
         )
-        io_cost = io_mb * scn.market.cost_per_bw_mb[dc_of_cl]
-        dc_seg = jnp.clip(dc_of_cl, 0, scn.hosts.n_dc - 1)
+        io_cost = io_mb * bw_price
         st = st.replace(
             cpu_cost=st.cpu_cost.at[dc_seg].add(run_cost),
             bw_cost=st.bw_cost.at[dc_seg].add(io_cost),

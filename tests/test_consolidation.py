@@ -319,8 +319,10 @@ def test_underload_drain_is_all_or_nothing(y, kept):
 # --- programs without a consolidation -----------------------------------
 
 # SimResult leaves and lowered program text of the four Figure-4 policy
-# pairs, one at a time and stacked, recorded from the program as it stood
-# before the consolidation subsystem existed
+# pairs, one at a time and stacked.  The results were recorded from the
+# program as it stood before the consolidation subsystem existed; the text
+# since the step reads its small tables by one-hot selects
+# (``segments.lookup``), which left every result's bits as they were
 FIG4_RESULTS = [
     "161d6a9af56f80970805f441e419485c7cdeab042c4af94ff811f93ffc76287c",
     "09209ff5ec50a74d5909a67c24324ff552c17463637fd071a8cda0b95fad9783",
@@ -329,8 +331,8 @@ FIG4_RESULTS = [
 ]
 FIG4_BATCH = "f4ebd8dad2c430ba4f98b588ee5262b9abe90f61b6b8802696287ebfeaf0e8d7"
 FIG4_LOWERED = (
-    "d6131a29c90411085b362e463e0c797e87d140defbe56ba62f079b74b7926ad1",
-    "f0f87442327edb08267802b3c3f1264c090a273b4f75dceef3702e888846ffd7",
+    "10daa5b15baa51f418711fb9b10b33e1110f56bf54da92cfd12667f143c6dda5",
+    "7ceee2a39d9523010e56801c38860117890aa87ea34ae454a24ca88b43771dd3",
 )
 
 

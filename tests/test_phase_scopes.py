@@ -12,21 +12,29 @@ Three programs are compiled on the CPU: ``simulate`` of one small scenario
 (simlint's own, which carries a topology so the transfer phase exists), of
 an 8-row stacked campaign (the batch-major step), and the streaming fold
 program that ``run_campaign(reduce=...)`` dispatches per chunk.
+
+The last tests read which tables the batch step still gathers from: the
+bound's and the loop condition's per-VM and per-cloudlet tables are read
+by ``segments.lookup``'s one-hot form up to ``segments.ONE_HOT_MAX``
+entries, and by a gather above it.
 """
+import math
 import re
 
 import jax
+import numpy as np
 import pytest
 
 from repro.analysis import simlint
 from repro.analysis.hlo_walk import HloWalker
-from repro.core import campaign, engine, reducers, scenarios, step
+from repro.core import campaign, engine, reducers, scenarios, segments, step
 from repro.core.entities import SPACE_SHARED, TIME_SHARED
 
 pytestmark = pytest.mark.tier1
 
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)$")
+_SHAPE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*\w+\[([\d,]*)\]")
 _CALLEE = re.compile(
     r"\b(condition|body|calls|true_computation|false_computation)="
     r"%?([\w.\-]+)|branch_computations=\{([^}]*)\}")
@@ -209,3 +217,72 @@ def test_every_loop_op_carries_a_step_scope(programs, program):
 def test_simlint_r1_still_passes(lint):
     findings = simlint.RULES["R1"].fn(lint)
     assert [f for f in findings if f.severity == "error"] == []
+
+
+# --- small-table lookups ----------------------------------------------------
+
+def _gathered_tables(hlo: str, scopes: tuple) -> list[int]:
+    """Entries per row of the table read by each gather under ``scopes``:
+    the gather's operand with its leading (batch) axis left out."""
+    sizes = []
+    for lines in HloWalker(hlo).comps.values():
+        dims = {}
+        for line in lines:
+            m = _SHAPE.match(line)
+            if m:
+                dims[m.group(1)] = [int(d) for d in m.group(2).split(",") if d]
+        for line in lines:
+            m = _INSTRUCTION.match(line)
+            if not m or _opcode(m.group(2)) != "gather":
+                continue
+            if not any(s in _op_name(line).split("/") for s in scopes):
+                continue
+            operand = re.search(r"\bgather\(%?([\w.\-]+)", line).group(1)
+            sizes.append(math.prod(dims[operand][1:]))
+    return sizes
+
+
+def _fig4_rows(n_cloudlets: int) -> list:
+    """Figure 4's host and VMs, ``n_cloudlets`` tasks dealt to both VMs."""
+    rows = []
+    for vp in (SPACE_SHARED, TIME_SHARED):
+        base = scenarios.fig4_scenario(SPACE_SHARED, vp)
+        cls = scenarios.make_cloudlets(
+            np.arange(n_cloudlets) % 2, np.full(n_cloudlets, 4000.0),
+            np.zeros(n_cloudlets), input_mb=0.0, output_mb=0.0)
+        rows.append(base.replace(cloudlets=cls))
+    return rows
+
+
+# (rows, host grid entries) of the batch programs the sweep cells run:
+# fig4 (8 tasks, 2 VMs, 1 host), fig9_10 (500 tasks, 50 VMs, 10,000 hosts)
+SHAPED = {
+    "fig4": lambda: (_fig4_rows(8), 1),
+    "fig9_10": lambda: ([scenarios.fig9_10_scenario(vp)
+                         for vp in (SPACE_SHARED, TIME_SHARED)], 10_000),
+}
+
+
+def _batch_gathers(rows) -> list[int]:
+    hlo = jax.jit(engine.simulate).lower(
+        campaign.stack_scenarios(rows)).compile().as_text()
+    return _gathered_tables(hlo, (step.SCOPE_BOUND, step.SCOPE_LOOP_COND))
+
+
+@pytest.mark.parametrize("shape", sorted(SHAPED))
+def test_bound_and_loop_cond_gather_only_the_host_grid(shape):
+    """No per-VM or per-cloudlet table is gathered under ``phase_bound``
+    or ``loop_cond``; what is left reads the host grid
+    (``policies.host_level_mips``), which ``segments.lookup`` does not
+    serve."""
+    rows, grid = SHAPED[shape]()
+    sizes = _batch_gathers(rows)
+    assert sizes and set(sizes) == {grid}, sizes
+
+
+def test_a_table_above_the_limit_keeps_its_gather():
+    """With more cloudlets than ``ONE_HOT_MAX`` the cloudlet permutation of
+    ``segment_prefix_sum`` is gathered again; the 2-VM tables stay one-hot."""
+    n = segments.ONE_HOT_MAX + 1
+    sizes = _batch_gathers(_fig4_rows(n))
+    assert sorted(s for s in sizes if s != 1) == [n, n], sizes
