@@ -194,7 +194,9 @@ def phase_skippable() -> None:
 
     on = scenarios.autoscale_scenario(jax.random.PRNGKey(0))
     off = on.replace(policy=on.policy.replace(autoscale=jnp.asarray(False)))
-    fn = jax.jit(simulate_instrumented)
+    # a fresh callable: jit caches pool per underlying function, so another
+    # jax.jit(simulate_instrumented) in this process would count here
+    fn = jax.jit(lambda s: simulate_instrumented(s))
     (res_on, out_on), first_s = run_timed(fn, on)
     (res_off, out_off), run_s = run_timed(fn, off)
     stats = {"jit_cache_entries": fn._cache_size(),

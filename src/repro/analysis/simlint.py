@@ -487,7 +487,7 @@ def check_rank_consistency(single_shapes: dict, batch_shapes: dict,
                            batch: int, entry: str,
                            rule_id: str = "R4") -> list[Finding]:
     """Each batch-path SimState leaf must be exactly ``[B] + single`` — the
-    contract that lets ``_freeze`` broadcast its row mask per leaf."""
+    contract that lets ``step.freeze`` broadcast its row mask per leaf."""
     findings = []
     for path, s_shape in single_shapes.items():
         b_shape = batch_shapes.get(path)

@@ -5,8 +5,10 @@ VM -> Cloudlet) with two-level space/time-shared scheduling, FCFS/best-fit VM
 provisioning, federation with sensor-driven migration, and market accounting
 — as one pure, jittable, vmappable JAX program (see DESIGN.md).
 
-The event-loop body lives exactly once (``step.event_step``); ``simulate``,
-``simulate_trace`` and ``simulate_history`` are thin drivers over it, and
+The event-loop body is composed exactly once, over one scenario or a
+stacked ``[B]`` batch as the input's rank chooses (``step.event_step`` and
+``step.batch_event_step`` are thin over it); ``simulate``, ``simulate_trace``
+and ``simulate_history`` are thin drivers, one per loop form, and
 cross-cutting observables (energy, market accrual, federation sensing, trace
 sampling, …) are composable ``step.Instrument``s.
 """

@@ -2,8 +2,11 @@
 
 CloudSim advances the world between *events*: rates are piecewise-constant,
 so each ``updateVMsProcessing()`` sweep returns the next expected completion
-time and the clock jumps straight to the earliest one.  The loop body lives
-exactly once, in ``core/step.py``; this module provides the three drivers:
+time and the clock jumps straight to the earliest one.  The loop body is
+composed once, in ``core/step.py``; this module writes each loop form once,
+over the row mapping (``step.Rows``) that the input's rank chooses: one
+scenario, or a stacked ``[B]`` campaign run batch-major (``is_batched``).
+Init, the loop condition, the step and finalize all go through it.
 
 * ``simulate``          — ``lax.while_loop`` until horizon/completion; the
                           production path, pure/jittable/vmappable.
@@ -35,7 +38,7 @@ import jax
 import jax.numpy as jnp
 from jax import Array
 
-from repro.core import consolidate
+from repro.core import consolidate, energy, policies
 from repro.core import step as step_mod
 from repro.core.entities import (
     INF,
@@ -129,8 +132,8 @@ def is_batched(scn: Scenario) -> bool:
     """Batch-major detection by rank (DESIGN.md §10): ``hosts.cores`` is
     ``[D, H]`` for one scenario, ``[B, D, H]`` for a stacked campaign.
     Under ``jax.vmap`` the per-row view is rank-2 again, so
-    ``vmap(simulate)`` still composes with the single-scenario path — which
-    is what keeps it an honest baseline for the batch-major drivers."""
+    ``vmap(simulate)`` still composes with the single-scenario form — which
+    is what keeps it an honest baseline for the batch-major form."""
     return jnp.ndim(scn.hosts.cores) == 3
 
 
@@ -139,69 +142,68 @@ def scenario_row(scn: Scenario, i: int = 0) -> Scenario:
     return jax.tree.map(lambda x: x[i], scn)
 
 
+def _start(scn: Scenario, extras: tuple):
+    """What both loop forms start from: the row mapping chosen by rank, the
+    step context, the initial carry and the step budget.
+
+    ``make_context`` / ``resolve_max_steps`` read only static shape and
+    instrument-structure information, so a batch's row 0 stands in for
+    every row (``stack_scenarios`` enforces static-field agreement).
+    """
+    rows = step_mod.Rows(batched=is_batched(scn))
+    with jax.named_scope(step_mod.SCOPE_INIT):
+        one = scenario_row(scn) if rows.batched else scn
+        ctx = make_context(one, extras)
+        carry = (rows.map(init_state)(scn),
+                 rows.map(lambda s: step_mod.init_aux(s, extras))(scn))
+    return rows, ctx, carry, step_mod.resolve_max_steps(one, ctx.instruments)
+
+
+def _step(rows, scn, carry, ctx, extras, max_steps, masked):
+    """One event step by the public name of the input's form, so a patched
+    ``event_step`` or ``batch_event_step`` is what runs.
+
+    Returns ``(carry', event, live)``.  A batch is always masked: its
+    finished rows stay frozen.  One scenario is masked only where asked: a
+    while loop steps it only while it runs (``live`` None), a fixed-length
+    scan also past its end.
+    """
+    if rows.batched:
+        return step_mod.batch_event_step(scn, carry, ctx, extras, max_steps)
+    if not masked:
+        return (*event_step(scn, carry, ctx), None)
+    live = rows.live(scn, carry[0], max_steps)
+    stepped, ev = event_step(scn, carry, ctx)
+    return step_mod.freeze(live, stepped, carry), ev, live
+
+
 def simulate_instrumented(
     scn: Scenario, extra_instruments: tuple = ()
 ) -> tuple[SimResult, dict]:
     """Run one simulation and collect instrument outputs (by instrument name).
 
     Instruments = step defaults + ``Scenario.instruments`` + ``extra_instruments``.
-    A stacked campaign (``is_batched``) routes through the batch-major step:
-    one compiled loop advances every row natively, finished rows frozen by
-    the live mask, per-row results bitwise those of the solo runs.
+    The one ``while_loop`` driver: a stacked campaign (``is_batched``) loops
+    while any row is live, every row advanced natively by the batch-major
+    step, finished rows frozen, per-row results bitwise those of the solo
+    runs.
     """
-    if is_batched(scn):
-        return _simulate_instrumented_batch(scn, tuple(extra_instruments))
-    with jax.named_scope(step_mod.SCOPE_INIT):
-        ctx, aux0 = make_context(scn, tuple(extra_instruments))
-        st0 = init_state(scn)
-    max_steps = step_mod.resolve_max_steps(scn, ctx.instruments)
+    extras = tuple(extra_instruments)
+    rows, ctx, carry0, max_steps = _start(scn, extras)
 
     def cond(carry) -> Array:
         with jax.named_scope(step_mod.SCOPE_LOOP_COND):
-            return step_mod.step_cond(scn, carry[0], max_steps)
+            return rows.any(rows.live(scn, carry[0], max_steps))
 
     def body(carry):
-        carry, _ = event_step(scn, carry, ctx)
-        return carry
+        return _step(rows, scn, carry, ctx, extras, max_steps, False)[0]
 
-    st, aux = jax.lax.while_loop(cond, body, (st0, aux0))
+    st, aux = jax.lax.while_loop(cond, body, carry0)
     with jax.named_scope(step_mod.SCOPE_FINALIZE):
-        return (finalize_result(scn, st),
-                step_mod.finalize_outputs(scn, st, ctx, aux))
-
-
-def _simulate_instrumented_batch(
-    scn_b: Scenario, extras: tuple
-) -> tuple[SimResult, dict]:
-    """Batch-major driver: ``while any(live)`` over ``batch_event_step``.
-
-    ``make_context`` / ``resolve_max_steps`` read only static shape and
-    instrument-structure information, so the row-0 view stands in for every
-    row (``stack_scenarios`` enforces static-field agreement).
-    """
-    with jax.named_scope(step_mod.SCOPE_INIT):
-        scn0 = scenario_row(scn_b)
-        ctx, _ = make_context(scn0, extras)
-        st0 = jax.vmap(init_state)(scn_b)
-        aux0 = jax.vmap(lambda s: step_mod.init_aux(s, extras))(scn_b)
-    max_steps = step_mod.resolve_max_steps(scn0, ctx.instruments)
-
-    def cond(carry) -> Array:
-        with jax.named_scope(step_mod.SCOPE_LOOP_COND):
-            return jnp.any(step_mod.batch_live(scn_b, carry[0], max_steps))
-
-    def body(carry):
-        carry, _, _ = step_mod.batch_event_step(
-            scn_b, carry, ctx, extras, max_steps
-        )
-        return carry
-
-    st, aux = jax.lax.while_loop(cond, body, (st0, aux0))
-    with jax.named_scope(step_mod.SCOPE_FINALIZE):
-        res = jax.vmap(finalize_result)(scn_b, st)
-        out = jax.vmap(
-            lambda s, f, a: step_mod.finalize_outputs_for(s, f, a, extras)
-        )(scn_b, st, aux)
+        res = rows.map(finalize_result)(scn, st)
+        out = rows.map(
+            lambda s, f, a: step_mod.finalize_outputs(s, f, a, extras)
+        )(scn, st, aux)
     return res, out
 
 
@@ -211,9 +213,10 @@ def entry_points() -> dict:
     ``analysis/simlint.py`` traces exactly these (plus the campaign chunk
     runner, its shard_map-sharded twin, and the Pallas advance kernel) when
     verifying the structural invariants of the compiled program — a new
-    driver added here is linted automatically.  ``simulate`` covers both
-    engine paths: handed a stacked campaign it routes through
-    ``batch_event_step`` (see ``is_batched``).
+    driver added here is linted automatically.  Each driver covers both
+    row mappings of the one composition: handed a stacked campaign it steps
+    through ``batch_event_step``, else through ``event_step`` (see
+    ``is_batched``).
     """
     return {
         "simulate": simulate,
@@ -269,95 +272,41 @@ class History:
     energy_j: Array     # [T, D] f32 accrued energy after the event
 
 
+def _record(scn: Scenario, st: SimState, ev: StepEvent, live: Array) -> History:
+    """One scenario's ``History`` row for an event (zeros unless live)."""
+    util = energy.dc_utilization(scn, st, vm_mips=ev.vm_mips)
+    n_fin = jnp.sum(
+        (policies.cloudlet_finished(st) & scn.cloudlets.exists).astype(jnp.int32)
+    )
+    return History(
+        t=jnp.where(live, ev.t1, 0.0),
+        dt=jnp.where(live, ev.dt, 0.0),
+        kind=jnp.where(live, ev.kind, -1),
+        valid=live,
+        n_finished=jnp.where(live, n_fin, 0),
+        utilization=jnp.where(live, util, 0.0),
+        cpu_cost=jnp.where(live, st.cpu_cost, 0.0),
+        bw_cost=jnp.where(live, st.bw_cost, 0.0),
+        energy_j=jnp.where(live, st.energy_j, 0.0),
+    )
+
+
 def simulate_history(scn: Scenario) -> tuple[SimResult, History]:
     """Run one simulation emitting the full per-event log.
 
-    A fixed-length ``lax.scan`` over ``event_step``: iterations past the end
-    carry the final state unchanged and emit invalid rows, so the result is
+    The one fixed-length ``lax.scan`` driver: iterations past the end carry
+    the final state unchanged and emit invalid rows, so the result is
     bit-identical to ``simulate`` while exposing the whole trajectory — the
     scenario-analysis surface (per-DC utilization/cost/energy timelines) the
-    while-loop drivers cannot produce.  A stacked campaign emits
+    while-loop driver cannot produce.  A stacked campaign emits
     ``[T, B, ...]`` records through the batch-major step (rows frozen once
     finished, exactly like their solo logs).
     """
-    from repro.core import energy as energy_mod
-    from repro.core import policies
-
-    if is_batched(scn):
-        return _simulate_history_batch(scn)
-
-    ctx, aux0 = make_context(scn)
-    max_steps = step_mod.resolve_max_steps(scn, ctx.instruments)
-    i32 = jnp.int32
+    rows, ctx, carry0, max_steps = _start(scn, ())
 
     def body(carry, _):
-        st, aux = carry
-        live = step_mod.step_cond(scn, st, max_steps)
-        (st2, aux2), ev = event_step(scn, (st, aux), ctx)
-        util = energy_mod.dc_utilization(scn, st2, vm_mips=ev.vm_mips)
-        n_fin = jnp.sum(
-            (policies.cloudlet_finished(st2) & scn.cloudlets.exists).astype(i32)
-        )
-        rec = History(
-            t=jnp.where(live, ev.t1, 0.0),
-            dt=jnp.where(live, ev.dt, 0.0),
-            kind=jnp.where(live, ev.kind, -1),
-            valid=live,
-            n_finished=jnp.where(live, n_fin, 0),
-            utilization=jnp.where(live, util, 0.0),
-            cpu_cost=jnp.where(live, st2.cpu_cost, 0.0),
-            bw_cost=jnp.where(live, st2.bw_cost, 0.0),
-            energy_j=jnp.where(live, st2.energy_j, 0.0),
-        )
-        carry = jax.tree.map(
-            lambda a, b: jnp.where(live, a, b), (st2, aux2), (st, aux)
-        )
-        return carry, rec
+        carry, ev, live = _step(rows, scn, carry, ctx, (), max_steps, True)
+        return carry, rows.map(_record)(scn, carry[0], ev, live)
 
-    (st, _), hist = jax.lax.scan(
-        body, (init_state(scn), aux0), None, length=max_steps
-    )
-    return finalize_result(scn, st), hist
-
-
-def _simulate_history_batch(scn_b: Scenario) -> tuple[SimResult, History]:
-    """Batch-major history: fixed-length scan over ``batch_event_step``;
-    ``History`` leaves get a ``[T, B, ...]`` layout."""
-    from repro.core import energy as energy_mod
-    from repro.core import policies
-
-    scn0 = scenario_row(scn_b)
-    ctx, _ = make_context(scn0)
-    max_steps = step_mod.resolve_max_steps(scn0, ctx.instruments)
-    st0 = jax.vmap(init_state)(scn_b)
-    aux0 = jax.vmap(step_mod.init_aux)(scn_b)
-    i32 = jnp.int32
-
-    def body(carry, _):
-        carry, ev, live = step_mod.batch_event_step(
-            scn_b, carry, ctx, (), max_steps
-        )
-        st2 = carry[0]
-
-        def record(scn, st_r, ev_r, live_r):
-            util = energy_mod.dc_utilization(scn, st_r, vm_mips=ev_r.vm_mips)
-            n_fin = jnp.sum(
-                (policies.cloudlet_finished(st_r)
-                 & scn.cloudlets.exists).astype(i32)
-            )
-            return History(
-                t=jnp.where(live_r, ev_r.t1, 0.0),
-                dt=jnp.where(live_r, ev_r.dt, 0.0),
-                kind=jnp.where(live_r, ev_r.kind, -1),
-                valid=live_r,
-                n_finished=jnp.where(live_r, n_fin, 0),
-                utilization=jnp.where(live_r, util, 0.0),
-                cpu_cost=jnp.where(live_r, st_r.cpu_cost, 0.0),
-                bw_cost=jnp.where(live_r, st_r.bw_cost, 0.0),
-                energy_j=jnp.where(live_r, st_r.energy_j, 0.0),
-            )
-
-        return carry, jax.vmap(record)(scn_b, st2, ev, live)
-
-    (st, _), hist = jax.lax.scan(body, (st0, aux0), None, length=max_steps)
-    return jax.vmap(finalize_result)(scn_b, st), hist
+    (st, _), hist = jax.lax.scan(body, carry0, None, length=max_steps)
+    return rows.map(finalize_result)(scn, st), hist

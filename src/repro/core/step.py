@@ -1,22 +1,28 @@
-"""The event-step kernel: the simulator's event-loop body, exactly once.
+"""The event-step kernel: the simulator's event-loop body, composed once.
 
-``simulate``, ``simulate_trace`` and ``simulate_history`` (engine.py) are thin
-drivers over one function — ``event_step`` — which advances the world by one
-event batch:
+``_compose`` advances the world by one event batch, over one scenario or a
+``[B]`` batch of them; a row mapping (``Rows``) chosen by the input's rank
+applies each per-row phase directly or under ``jax.vmap``.  ``event_step``
+and ``batch_event_step`` are thin over it, and the drivers (engine.py) are
+thin over those.  In program order:
 
     0. host failure/repair edges     (outage schedule: evict + roll back)
     1. instrument ``pre`` hooks      (Sensor tick lives here)
        consolidation pass            (at scheduling ticks, when
                                       ``Scenario.dynamic_consolidation``
                                       is set)
-    2. VM lifecycle                  (release drained, place due requests)
+    2. the conditioned phases        (``_CONDITIONED``: VM placement, broker
+                                      dispatch, transfers, the KV ledger;
+                                      each skipped when nothing is due)
     3. policy sweep                  (per-cloudlet MIPS rates)
     4. next-event bound              (ready / request / migration / failure /
                                       repair / instrument bounds / horizon)
     5. fused advance                 (min-time-to-completion + work depletion,
-                                      jnp or Pallas — resolved once per driver)
+                                      jnp or Pallas — resolved once per
+                                      driver; outside the row map)
     6. instrument ``post`` hooks     (market accrual, energy integration,
                                       trace sampling, custom observables)
+    7. freeze                        (a batch's finished rows keep their state)
 
 Cross-cutting observables are **Instruments**: small pytrees with
 ``init / pre / bound / post / finalize`` hooks threaded through the loop as an
@@ -28,6 +34,7 @@ argument and §3 for the instrument contract.
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Callable
 
 import jax
@@ -63,28 +70,27 @@ K_SERVING = 11     # a decoding request crossed a KV-block boundary (§14)
 # how simlint rule R1 verifies the predicates survive XLA lowering as real
 # ``conditional`` ops with branch computations — not flattened into ``select``
 # (the vmap degradation that silently pays both branches, DESIGN.md §10/§11).
+# ``PHASE_SCOPES`` (below ``_CONDITIONED``) lists them.  SCOPE_TRANSFER and
+# SCOPE_CONSOLIDATE only exist in programs traced with a topology or a
+# consolidation attached; simlint's lint scenarios carry both so R1 covers
+# every phase.
 SCOPE_PROVISION = "phase_provision"
 SCOPE_DISPATCH = "phase_dispatch"
 SCOPE_TRANSFER = "phase_transfer"
 SCOPE_SERVING = "phase_serving"
 SCOPE_CONSOLIDATE = "phase_consolidate"
-# SCOPE_TRANSFER and SCOPE_CONSOLIDATE only exist in programs traced with a
-# topology or a consolidation attached; simlint's lint scenarios carry both
-# so R1 covers every phase.
-PHASE_SCOPES = (SCOPE_PROVISION, SCOPE_DISPATCH, SCOPE_TRANSFER, SCOPE_SERVING,
-                SCOPE_CONSOLIDATE)
 
 # Named scopes over the rest of the program, so a profiler trace assigns
 # nearly every device op to a phase (bench/metrics reads them by name).  They
 # sit on no ``conditional``, so they stay out of PHASE_SCOPES (simlint R1).
-# Each is opened at the call site, around a phase's ``jax.vmap`` on the batch
-# path: a scope opened inside a vmapped function renders as
-# ``vmap(phase_bound)`` and no longer matches as a whole path component.
+# Each is opened around the row-mapped call, not inside it: a scope opened
+# inside a vmapped function renders as ``vmap(phase_bound)`` and no longer
+# matches as a whole path component.
 SCOPE_PROLOGUE = "phase_prologue"   # outage edges, transfers, pre hooks, release
 SCOPE_BOUND = "phase_bound"         # policy sweep, candidate times, bound hooks
 SCOPE_ADVANCE = "phase_advance"     # ctx.advance, jnp or Pallas
 SCOPE_COMMIT = "phase_commit"       # state update, post hooks
-SCOPE_LOOP_COND = "loop_cond"       # the while drivers' condition, batch_live
+SCOPE_LOOP_COND = "loop_cond"       # the while driver's condition, Rows.live
 SCOPE_FREEZE = "phase_freeze"       # batch path: finished rows' row select
 SCOPE_INIT = "sim_init"             # init_state / init_aux in the drivers
 SCOPE_FINALIZE = "sim_finalize"     # finalize_result / finalize_outputs
@@ -754,10 +760,10 @@ def instruments_for(
     """The full instrument tuple a driver threads through the loop.
 
     Order — defaults, then ``Scenario.instruments``, then driver extras — is
-    the accrual order inside each step.  The batch-major step rebuilds this
-    inside its vmapped phase closures, so per-row instrument leaves (a
-    campaign sweeping instrument fields) map correctly while driver extras
-    stay captured unbatched.
+    the accrual order inside each step.  ``batch_event_step`` rebuilds this
+    inside its vmapped phases, so per-row instrument leaves (a campaign
+    sweeping instrument fields) map correctly while driver extras stay
+    captured unbatched.
     """
     return default_instruments() + tuple(scn.instruments) + tuple(
         extra_instruments
@@ -765,7 +771,8 @@ def instruments_for(
 
 
 def init_aux(scn: Scenario, extra_instruments: tuple = ()) -> tuple:
-    """Initial instrument aux states (vmapped per row by the batch drivers)."""
+    """Initial instrument aux states of one scenario (vmapped per row by
+    the drivers for a batch)."""
     return tuple(
         ins.init(scn) for ins in instruments_for(scn, extra_instruments)
     )
@@ -791,8 +798,8 @@ def _check_dynamic_consolidation(scn: Scenario, instruments: tuple) -> None:
 
 def make_context(
     scn: Scenario, extra_instruments: tuple = ()
-) -> tuple[StepContext, tuple]:
-    """Build the step context + initial instrument aux states for a driver.
+) -> StepContext:
+    """Build a driver's step context from one scenario (a batch's row 0).
 
     Instrument order — defaults, then ``Scenario.instruments``, then driver
     extras — is the accrual order inside each step.
@@ -806,27 +813,27 @@ def make_context(
             f"duplicate instrument name(s) {sorted(dupes)}: outputs are keyed "
             "by name — give each instance a distinct `name` class attr"
         )
-    ctx = StepContext(
+    return StepContext(
         instruments=instruments,
         advance=resolve_advance(scn),
     )
-    aux = tuple(ins.init(scn) for ins in instruments)
-    return ctx, aux
 
 
 # ---------------------------------------------------------------------------
-# the event-step phases (DESIGN.md §10)
+# the event-step phases, composed once (DESIGN.md §10)
 #
-# ``event_step`` is decomposed into phase functions so the batch-major step
-# can vmap each phase over the scenario axis while keeping the expensive
-# phases (the provisioning scan, broker dispatch) behind *scalar*
-# ``lax.cond``s on batch-global predicates.  Under vmap a batched-predicate
-# cond degrades to a select (both branches execute); a scalar predicate on
-# the whole batch genuinely skips the phase — the structural advantage the
-# batch-major path has over vmap-of-``simulate``.  Each skipped phase is an
-# exact identity whenever its predicate is False (every write inside is
-# gated by the same ``due`` mask the predicate reduces), so skipping
-# preserves bitwise identity.
+# ``_compose`` runs the phases over one scenario or a ``[B]`` batch through a
+# row mapping (``Rows``) chosen by the input's rank.  The expensive phases
+# (the provisioning scan, broker dispatch, transfers, the KV ledger, the
+# consolidation pass) sit behind *scalar* ``lax.cond``s: for one scenario
+# the phase's "needed" predicate as is, for a batch ``any(needed & live)``.
+# Under vmap a batched-predicate cond degrades to a select (both branches
+# execute); a scalar predicate on the whole batch genuinely skips the phase
+# — the structural advantage the batch-major path has over
+# vmap-of-``simulate``.  Each skipped conditioned phase is an exact identity
+# whenever its predicate is False (every write inside is gated by the same
+# ``due`` mask the predicate reduces), so skipping preserves bitwise
+# identity; the consolidation pass is not, so it is gated per row.
 # ---------------------------------------------------------------------------
 
 
@@ -847,6 +854,51 @@ def _dispatch_needed(scn: Scenario, st: SimState) -> Array:
     mask of ``provision.dispatch_cloudlets``)."""
     cls = scn.cloudlets
     return jnp.any(cls.exists & (st.cl_vm < 0) & (cls.submit_t <= st.t))
+
+
+# The conditioned phases in program order: (scope, needed, phase, requires).
+# ``requires`` names a static Scenario field the phase exists only with (not
+# traced when it is None).  A new conditioned phase is one row here.
+_CONDITIONED = (
+    # VM placement: the provisioning scan over due requests
+    (SCOPE_PROVISION, _provision_needed,
+     lambda scn, st: provision.provision_due_vms(scn, st)[0], None),
+    # broker dispatch of submitted service-routed cloudlets
+    (SCOPE_DISPATCH, _dispatch_needed, provision.dispatch_cloudlets, None),
+    # contention-aware transfers: open due stage-ins, re-time in-flight
+    # transfers on occupancy-changed links (DESIGN.md §13)
+    (SCOPE_TRANSFER, provision.transfer_needed, provision.transfer_phase,
+     "topology"),
+    # KV-block ledger sweep: release / growth / eviction / admission for
+    # LLM-serving rows (§14)
+    (SCOPE_SERVING, kvserve.serving_needed, kvserve.serving_phase, None),
+)
+# The scopes of every cond-skipped phase, which simlint rule R1 checks.
+PHASE_SCOPES = tuple(row[0] for row in _CONDITIONED) + (SCOPE_CONSOLIDATE,)
+
+
+@dataclasses.dataclass(frozen=True)
+class Rows:
+    """The row mapping: how the composition and its drivers reach the rows.
+
+    Chosen by the input's rank (``engine.is_batched``), never by a caller.
+    For one scenario a per-row function applies as is and a predicate is
+    the cond's own; for a ``[B]`` batch the function is vmapped and the
+    predicate reduced to a scalar with ``any``.
+    """
+
+    batched: bool
+
+    def map(self, fn: Callable) -> Callable:
+        return jax.vmap(fn) if self.batched else fn
+
+    def any(self, p: Array) -> Array:
+        return jnp.any(p) if self.batched else p
+
+    def live(self, scn: Scenario, st: SimState, max_steps: int) -> Array:
+        """``step_cond`` of every row: scalar, or the ``[B]`` live mask."""
+        return self.map(lambda scn, st: step_cond(scn, st, max_steps))(
+            scn, st)
 
 
 def _phase_prologue(
@@ -1012,119 +1064,103 @@ def _phase_commit(
     return (st, tuple(aux)), ev
 
 
-def event_step(
-    scn: Scenario, carry: tuple[SimState, tuple], ctx: StepContext
+def freeze(live: Array, new, old):
+    """Per-leaf row select: live rows take the stepped value, finished rows
+    stay bitwise frozen at their final state (early-exit masking).
+    ``live`` is a ``[B]`` mask, or a scalar for one scenario."""
+    return jax.tree.map(
+        lambda a, b: jnp.where(
+            live.reshape(live.shape + (1,) * (a.ndim - live.ndim)), a, b
+        ),
+        new,
+        old,
+    )
+
+
+def _compose(
+    scn: Scenario,
+    carry: tuple[SimState, tuple],
+    ctx: StepContext,
+    rows: Rows,
+    instruments: Callable,
+    live: Array | None = None,
 ) -> tuple[tuple[SimState, tuple], StepEvent]:
-    """Advance the world by one event batch.  THE event-loop body.
+    """One event batch over ``rows``: THE event-loop body, composed once.
 
-    ``carry`` is ``(SimState, instrument aux tuple)``; returns the stepped
-    carry plus the emitted ``StepEvent``.  Pure, jittable, vmappable; every
-    driver — while_loop or scan — wraps exactly this function (the
-    batch-major drivers wrap ``batch_event_step``, which composes the same
-    phases over a ``[B, ...]`` scenario axis).
-
-    The provisioning scan and broker dispatch sit behind scalar
-    ``lax.cond``s: most events have no due VM request and no unbound
-    cloudlet, and both phases are exact identities then, so skipping them is
-    free throughput at bitwise-identical results.  (Under vmap the conds
-    lower to selects — both branches run — which is exactly the pre-refactor
-    cost; the batch-major path keeps the predicates batch-global and scalar,
-    so *it* genuinely skips.)
+    ``instruments(scn_row)`` gives a row's instrument tuple.  ``live`` (a
+    batch's ``[B]`` mask, or None) takes finished rows out of every skip
+    predicate and freezes them.  Each scope is opened around the mapped
+    call, not inside it, where a vmap would rename it.  The advance runs
+    outside the map on the whole block, so one kernel call serves a batch.
     """
-    st, aux = carry
-    instruments = ctx.instruments
+    st0, aux0 = carry
+
+    def mask(p: Array) -> Array:
+        return p if live is None else p & live
 
     with jax.named_scope(SCOPE_PROLOGUE):
-        st, aux = _phase_prologue(scn, st, aux, instruments)
+        st, aux = rows.map(lambda scn, st, aux: _phase_prologue(
+            scn, st, aux, instruments(scn)))(scn, st0, aux0)
 
     # --- the consolidation pass, at scheduling ticks only (DESIGN.md §15) ---
     if scn.dynamic_consolidation is not None:
         with jax.named_scope(SCOPE_CONSOLIDATE):
-            due = consolidate.tick_due(scn, st)
+            due = mask(rows.map(consolidate.tick_due)(scn, st))
             first = st.consol.k == 0
-            st = jax.lax.cond(
-                due & first, lambda s: consolidate.initial_pass(scn, s),
-                lambda s: s, st)
-            st = jax.lax.cond(
-                due & ~first, lambda s: consolidate.tick_pass(scn, s),
-                lambda s: s, st)
+            for fn, p in ((consolidate.initial_pass, due & first),
+                          (consolidate.tick_pass, due & ~first)):
+                st = jax.lax.cond(
+                    rows.any(p),
+                    lambda s, fn=fn, p=p: rows.map(
+                        lambda scn, st, p: consolidate.gated(fn, p, scn, st)
+                    )(scn, s, p),
+                    lambda s: s,
+                    st,
+                )
 
-    # --- VM placement + broker dispatch, skipped when nothing is due ---
-    with jax.named_scope(SCOPE_PROVISION):
-        st = jax.lax.cond(
-            _provision_needed(scn, st),
-            lambda s: provision.provision_due_vms(scn, s)[0],
-            lambda s: s,
-            st,
-        )
-    with jax.named_scope(SCOPE_DISPATCH):
-        st = jax.lax.cond(
-            _dispatch_needed(scn, st),
-            lambda s: provision.dispatch_cloudlets(scn, s),
-            lambda s: s,
-            st,
-        )
-
-    # --- contention-aware transfer phase: open due stage-ins, re-time
-    #     in-flight transfers on occupancy-changed links (DESIGN.md §13) ---
-    if scn.topology is not None:
-        with jax.named_scope(SCOPE_TRANSFER):
+    for scope, needed, phase, requires in _CONDITIONED:
+        if requires is not None and getattr(scn, requires) is None:
+            continue
+        with jax.named_scope(scope):
             st = jax.lax.cond(
-                provision.transfer_needed(scn, st),
-                lambda s: provision.transfer_phase(scn, s),
+                rows.any(mask(rows.map(needed)(scn, st))),
+                lambda s, phase=phase: rows.map(phase)(scn, s),
                 lambda s: s,
                 st,
             )
 
-    # --- KV-block ledger sweep: release / growth / eviction / admission
-    #     for LLM-serving rows; skipped (and bitwise inert) without any ---
-    with jax.named_scope(SCOPE_SERVING):
-        st = jax.lax.cond(
-            kvserve.serving_needed(scn, st),
-            lambda s: kvserve.serving_phase(scn, s),
-            lambda s: s,
-            st,
-        )
-
     with jax.named_scope(SCOPE_BOUND):
-        rate, vm_mips, active, bound_dt, cand_ts = _phase_bound(
-            scn, st, aux, instruments
-        )
+        rate, vm_mips, active, bound_dt, cand_ts = rows.map(
+            lambda scn, st, aux: _phase_bound(scn, st, aux, instruments(scn))
+        )(scn, st, aux)
 
     # --- fused advance: completion min-reduce + work depletion ---
     with jax.named_scope(SCOPE_ADVANCE):
         dt, new_rem = ctx.advance(st.rem_mi, rate, active, bound_dt)
 
     with jax.named_scope(SCOPE_COMMIT):
-        return _phase_commit(
-            scn, st, aux, instruments, rate, vm_mips, active, cand_ts, dt,
-            new_rem,
-        )
+        (st, aux), ev = rows.map(
+            lambda scn, st, aux, *step: _phase_commit(
+                scn, st, aux, instruments(scn), *step)
+        )(scn, st, aux, rate, vm_mips, active, cand_ts, dt, new_rem)
+
+    if live is not None:
+        with jax.named_scope(SCOPE_FREEZE):
+            return freeze(live, (st, aux), (st0, aux0)), ev
+    return (st, aux), ev
 
 
-# ---------------------------------------------------------------------------
-# batch-major step: the campaign dimension inside the program (DESIGN.md §10)
-# ---------------------------------------------------------------------------
+def event_step(
+    scn: Scenario, carry: tuple[SimState, tuple], ctx: StepContext
+) -> tuple[tuple[SimState, tuple], StepEvent]:
+    """Advance one scenario by one event batch.
 
-
-def batch_live(scn_b: Scenario, st_b: SimState, max_steps: int) -> Array:
-    """[B] per-row loop-continuation mask — ``step_cond`` vmapped over the
-    scenario axis.  The batch drivers' loop condition is ``any(live)``."""
-    return jax.vmap(lambda scn, st: step_cond(scn, st, max_steps))(
-        scn_b, st_b
-    )
-
-
-def _freeze(live: Array, new, old):
-    """Per-leaf row select: live rows take the stepped value, finished rows
-    stay bitwise frozen at their final state (early-exit masking)."""
-    return jax.tree.map(
-        lambda a, b: jnp.where(
-            live.reshape(live.shape + (1,) * (a.ndim - 1)), a, b
-        ),
-        new,
-        old,
-    )
+    ``carry`` is ``(SimState, instrument aux tuple)``; returns the stepped
+    carry plus the emitted ``StepEvent``.  Pure, jittable, vmappable.  The
+    conditioned phases are skipped, exactly, when nothing is due for them.
+    """
+    return _compose(scn, carry, ctx, Rows(batched=False),
+                    lambda _: ctx.instruments)
 
 
 def batch_event_step(
@@ -1136,131 +1172,30 @@ def batch_event_step(
 ) -> tuple[tuple[SimState, tuple], StepEvent, Array]:
     """Advance a ``[B, ...]`` batch of scenarios by one event batch each.
 
-    The same phases as ``event_step``, vmapped over the scenario axis, with
-    three batch-major specifics:
-
-    * **phase skipping** — the provisioning scan and broker dispatch run
-      under *scalar* ``lax.cond``s on batch-global predicates
-      (``any(needed & live)``), so an event where no live row has work for
-      the phase skips it for the whole batch — the cost structure
-      vmap-of-``simulate`` cannot express (its conds lower to selects).
-    * **batch-grid advance** — the advance sweep is called *outside* the
-      vmapped phases on the full ``[B, C]`` block, so ``sweep_impl="pallas"``
-      lands on the fused batch-grid kernel (one grid step per scenario row).
-    * **early-exit masking** — rows whose ``step_cond`` is already False are
-      frozen: every state/aux write is row-gated by ``live``, so a finished
-      scenario's trajectory is bitwise that of its solo run no matter how
-      long the batch keeps looping.
-
-    Instruments are rebuilt per row inside the vmapped closures
+    The same composition as ``event_step``, vmapped over the scenario
+    axis.  A phase is skipped for the whole batch when no live row needs
+    it.  Rows whose ``step_cond`` is already False are frozen, so a
+    finished scenario's trajectory is bitwise that of its solo run however
+    long the batch keeps looping.  Instruments are rebuilt per row
     (``instruments_for``), so batched ``Scenario.instruments`` leaves map
-    per-row while driver ``extra_instruments`` stay captured unbatched.
-    Returns ``(carry', event batch, live)`` — dead rows' event fields are
-    garbage and must be masked with ``live`` by observers.
+    per row while ``extra_instruments`` stay captured unbatched.  Returns
+    ``(carry', event batch, live)``; dead rows' event fields are garbage
+    and must be masked with ``live`` by observers.
     """
-    st_b, aux_b = carry
     extras = tuple(extra_instruments)
+    rows = Rows(batched=True)
     with jax.named_scope(SCOPE_LOOP_COND):
-        live = batch_live(scn_b, st_b, max_steps)
-
-    def prologue(scn, st, aux):
-        return _phase_prologue(scn, st, aux, instruments_for(scn, extras))
-
-    with jax.named_scope(SCOPE_PROLOGUE):
-        st1, aux1 = jax.vmap(prologue)(scn_b, st_b, aux_b)
-
-    if scn_b.dynamic_consolidation is not None:
-        with jax.named_scope(SCOPE_CONSOLIDATE):
-            due = jax.vmap(consolidate.tick_due)(scn_b, st1) & live
-            first = st1.consol.k == 0
-            for fn, rows in ((consolidate.initial_pass, due & first),
-                             (consolidate.tick_pass, due & ~first)):
-                st1 = jax.lax.cond(
-                    jnp.any(rows),
-                    lambda s, fn=fn, rows=rows: jax.vmap(
-                        lambda scn, st, p: consolidate.gated(fn, p, scn, st)
-                    )(scn_b, s, rows),
-                    lambda s: s,
-                    st1,
-                )
-
-    # --- VM placement + broker dispatch: batch-global skip predicates ---
-    with jax.named_scope(SCOPE_PROVISION):
-        need_prov = jnp.any(jax.vmap(_provision_needed)(scn_b, st1) & live)
-        st2 = jax.lax.cond(
-            need_prov,
-            lambda s: jax.vmap(
-                lambda scn, st: provision.provision_due_vms(scn, st)[0]
-            )(scn_b, s),
-            lambda s: s,
-            st1,
-        )
-    with jax.named_scope(SCOPE_DISPATCH):
-        need_disp = jnp.any(jax.vmap(_dispatch_needed)(scn_b, st2) & live)
-        st3 = jax.lax.cond(
-            need_disp,
-            lambda s: jax.vmap(provision.dispatch_cloudlets)(scn_b, s),
-            lambda s: s,
-            st2,
-        )
-
-    if scn_b.topology is not None:
-        with jax.named_scope(SCOPE_TRANSFER):
-            need_xfer = jnp.any(
-                jax.vmap(provision.transfer_needed)(scn_b, st3) & live
-            )
-            st3 = jax.lax.cond(
-                need_xfer,
-                lambda s: jax.vmap(provision.transfer_phase)(scn_b, s),
-                lambda s: s,
-                st3,
-            )
-
-    with jax.named_scope(SCOPE_SERVING):
-        need_srv = jnp.any(
-            jax.vmap(kvserve.serving_needed)(scn_b, st3) & live
-        )
-        st3 = jax.lax.cond(
-            need_srv,
-            lambda s: jax.vmap(kvserve.serving_phase)(scn_b, s),
-            lambda s: s,
-            st3,
-        )
-
-    def bound(scn, st, aux):
-        return _phase_bound(scn, st, aux, instruments_for(scn, extras))
-
-    with jax.named_scope(SCOPE_BOUND):
-        rate, vm_mips, active, bound_dt, cand_ts = jax.vmap(bound)(
-            scn_b, st3, aux1
-        )
-
-    # --- batch-grid advance on the whole [B, C] block (outside the vmap) ---
-    with jax.named_scope(SCOPE_ADVANCE):
-        dt, new_rem = ctx.advance(st3.rem_mi, rate, active, bound_dt)
-
-    def commit(scn, st, aux, rate, vm_mips, active, cand_ts, dt, new_rem):
-        return _phase_commit(
-            scn, st, aux, instruments_for(scn, extras),
-            rate, vm_mips, active, cand_ts, dt, new_rem,
-        )
-
-    with jax.named_scope(SCOPE_COMMIT):
-        (st4, aux2), ev = jax.vmap(commit)(
-            scn_b, st3, aux1, rate, vm_mips, active, cand_ts, dt, new_rem
-        )
-
-    with jax.named_scope(SCOPE_FREEZE):
-        carry2 = _freeze(live, (st4, aux2), (st_b, aux_b))
-    return carry2, ev, live
+        live = rows.live(scn_b, carry[0], max_steps)
+    carry, ev = _compose(scn_b, carry, ctx, rows,
+                         lambda scn: instruments_for(scn, extras), live)
+    return carry, ev, live
 
 
-def finalize_outputs_for(
+def finalize_outputs(
     scn: Scenario, st: SimState, aux: tuple, extra_instruments: tuple = ()
 ) -> dict:
-    """Collect instrument outputs keyed by name, rebuilding the instrument
-    tuple from the (per-row) scenario — the batch drivers' vmapped twin of
-    ``finalize_outputs``."""
+    """Collect one scenario's instrument outputs, keyed by instrument
+    name (vmapped per row by the drivers for a batch)."""
     out: dict = {}
     for ins, a in zip(instruments_for(scn, extra_instruments), aux):
         o = ins.finalize(scn, st, a)
@@ -1332,15 +1267,3 @@ def finalize_result(scn: Scenario, st: SimState) -> SimResult:
         power=(None if scn.dynamic_consolidation is None
                else consolidate.finalize(scn, st)),
     )
-
-
-def finalize_outputs(
-    scn: Scenario, st: SimState, ctx: StepContext, aux: tuple
-) -> dict:
-    """Collect instrument outputs keyed by instrument name."""
-    out: dict = {}
-    for ins, a in zip(ctx.instruments, aux):
-        o = ins.finalize(scn, st, a)
-        if o:
-            out[ins.name] = o
-    return out

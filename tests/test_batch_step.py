@@ -112,6 +112,15 @@ def _scenario_batches():
             scenarios.evacuation_scenario(evacuation=False,
                                           ckpt_interval=3.0e38),
         ]),
+        # a static topology: the transfer phase, batch against solo
+        ("staging", [
+            scenarios.staging_scenario(),
+            scenarios.staging_scenario(input_mb=128.0, bw_mbps=50.0),
+        ]),
+        ("balance", [
+            scenarios.balance_scenario(live_migration=True),
+            scenarios.balance_scenario(live_migration=False),
+        ]),
     ]
 
 

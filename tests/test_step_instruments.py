@@ -212,17 +212,34 @@ def test_bound_instrument_extra_steps_prevents_truncation():
 
 
 def test_event_step_is_the_only_loop_body():
-    """Guard the tentpole: the drivers may not re-implement the loop body.
-
-    `simulate`, `simulate_trace` and `simulate_history` must all route
-    through step.event_step — asserted structurally: engine.py contains no
-    policy-sweep or advance calls of its own.
+    """Guard the one composition: the phases are wired up in exactly one
+    function of step.py, the conditioned-phase table is where simlint R1's
+    scopes come from, and engine.py writes each loop form once and calls
+    no phase of its own.
     """
+    import ast
     import inspect
 
     from repro.core import engine
 
+    tree = ast.parse(inspect.getsource(step))
+    callers: dict = {}
+    for fn in tree.body:
+        if not isinstance(fn, ast.FunctionDef):
+            continue
+        for node in ast.walk(fn):
+            if isinstance(node, ast.Call) and isinstance(node.func, ast.Name):
+                callers.setdefault(node.func.id, set()).add(fn.name)
+    for phase in ("_phase_prologue", "_phase_bound", "_phase_commit"):
+        assert callers.get(phase) == {"_compose"}, (phase, callers.get(phase))
+    assert callers["_compose"] == {"event_step", "batch_event_step"}
+
+    assert step.PHASE_SCOPES == tuple(
+        row[0] for row in step._CONDITIONED) + (step.SCOPE_CONSOLIDATE,)
+
     src = inspect.getsource(engine)
+    assert src.count("while_loop(") == 1
+    assert src.count("scan(") == 1
+    assert "_phase_" not in src
     assert "cloudlet_rates" not in src
     assert "advance(" not in src
-    assert src.count("event_step(scn,") == 2  # while-loop + scan drivers
